@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import ExperimentSpec, build_engine, resolve_compressor
+from repro.launch.mesh import make_mesh
 
 # the paper's sparse family; 'rand_k' is the registry's random_k
 COMPRESSORS = (("top_k", "top_k"), ("block_top_k", "block_top_k"),
@@ -141,7 +142,7 @@ def bench_sharded(d: int, frac: float, reps: int):
               f"have {len(jax.devices())} (run with --sharded from the CLI "
               "so the host-device flag is set before jax init)")
         return []
-    mesh = jax.make_mesh((n_data, n_model), ("data", "model"))
+    mesh = make_mesh((n_data, n_model), ("data", "model"))
     n = n_data
     d_sh = max(d - d // 3 - 1, 2) // (2 * n_model) * (2 * n_model)
     d_rep = max(d - d_sh, 1)
@@ -223,7 +224,7 @@ def bench_achieved_bytes(reps: int):
               f"{len(jax.devices())} (run --achieved-bytes from the CLI so "
               "the host-device flag is set before jax init)")
         return None
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     windows = 8
     d = windows * WF.PACK_BLOCK                     # window-aligned
     specs = {"w": P("data", None)}

@@ -46,11 +46,12 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis import hlo as H
 from repro.api import ExperimentSpec, build
 from repro.data import a9a_like, minibatch_source, shard_to_agents
+from repro.launch.mesh import make_mesh
 from repro.launch.runtime import make_runner
 
 # the paper's Section-5.1 protocol (standalone, like bench_train_loop.py)
@@ -121,7 +122,8 @@ def _wire_loss(p, b):
 def hlo_gossip_bytes(plane_dtype) -> int:
     """Sum collective result bytes attributed to the gossip executor in the
     compiled porter-gc step (ring, 4 host agents)."""
-    mesh = Mesh(np.asarray(jax.devices()[:WIRE_N]), ("data",))
+    mesh = make_mesh((WIRE_N,), ("data",),
+                     devices=jax.devices()[:WIRE_N])
     spec = ExperimentSpec(algo="porter-gc", n_agents=WIRE_N, topology="ring",
                           topology_weights="metropolis",
                           compressor="block_top_k", frac=0.25,

@@ -42,11 +42,12 @@ if __package__ in (None, ""):  # `python benchmarks/fleet_ablation.py`
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.api import ExperimentSpec, build
 from repro.core import FLEET_DENSE_GATE
 from repro.data import a9a_like, dirichlet_source
+from repro.launch.mesh import make_mesh
 from repro.launch.runtime import make_runner
 from benchmarks import common as C
 
@@ -74,7 +75,7 @@ def _fleet_shardings(state, batch_shape, n):
     devs = jax.devices()
     if len(devs) < 2 or n % len(devs) != 0:
         return None, None
-    mesh = Mesh(np.asarray(devs), ("fleet",))
+    mesh = make_mesh((len(devs),), ("fleet",), devices=devs)
 
     def spec(leaf):
         if getattr(leaf, "ndim", 0) >= 1 and leaf.shape[0] == n:

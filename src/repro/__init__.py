@@ -35,7 +35,6 @@ Module map:
     models, nn  the model zoo and its building blocks
     data        synthetic datasets matching the paper's experiments
     configs     per-architecture ModelConfigs (paper + production scale)
-    compat      jax version shims (shard_map)
 """
 
 __version__ = "0.1.0"

@@ -37,6 +37,7 @@ from repro.core import FLEET_DENSE_GATE
 from repro.core import wire_formats as WF
 from repro.core.registry import algorithm_info, list_algorithms
 from repro.data import minibatch_source
+from repro.launch.mesh import make_mesh
 
 from . import hlo as H
 
@@ -75,7 +76,7 @@ def make_agent_mesh(n: int = N_AGENTS) -> Mesh:
             f"{len(devs)} -- run via `python -m repro.analysis` (it forces "
             "host devices before jax init) or set "
             "--xla_force_host_platform_device_count")
-    return Mesh(np.asarray(devs[:n]), ("data",))
+    return make_mesh((n,), ("data",), devices=devs[:n])
 
 
 def census_loss(p, b):

@@ -593,10 +593,9 @@ def build_engine(spec: ExperimentSpec, *,
     make_topology/make_mixer/CommRound by hand.
 
     mesh/leaf_specs/agent_axes feed both the gossip executor (ring/packed
-    wire formats) and the engine's pallas path: leaf specs that carry model
-    axes switch the fused update to per-shard planes (pack/unpack inside
-    shard_map), so ``comm_backend='pallas'`` stays reshard-free on
-    tensor-parallel layouts.
+    wire formats) and the engine's pallas path: on a mesh the fused update
+    runs on per-shard planes inside shard_map, so ``comm_backend='pallas'``
+    stays reshard-free on every layout.
 
     When the spec declares a ``topology_schedule`` (or ``schedule`` is
     passed directly), the mixer is built from the schedule's stacked table

@@ -13,14 +13,14 @@ CHOCO-SGD, SoteriaFL) repeats the same per-round pattern around a buffer
 :class:`CommRound` owns that pattern once.  Compression and mixing run in
 the *pytree domain* (so shard-local compressors and the ring/packed wire
 executors keep their PartitionSpecs), while the AXPY chain of the update
-runs over the flat tile layout of :mod:`repro.kernels.flatten` so the fused
+runs leaf by leaf through :mod:`repro.kernels.flatten` so the fused
 Pallas kernels (:mod:`repro.kernels.ef_update`) touch each parameter once
 per round instead of ~13 separate HBM-bound tree_map passes.
 
 Backends:
 
-* ``'pallas'`` -- flatten to (tiles, 8*1024) planes, run ef_track /
-  ef_step / ef_gossip (Mosaic on TPU; pass ``interpret=True`` for CPU CI).
+* ``'pallas'`` -- run ef_track / ef_step / ef_gossip on every state leaf
+  (Mosaic on TPU; pass ``interpret=True`` for CPU CI).
 * ``'ref'``    -- pure-jnp tree_map chain, bit-identical to the pre-engine
   per-algorithm bodies; the numerical oracle.
 * ``'auto'``   -- 'pallas' on TPU, 'ref' elsewhere (the default, resolved
@@ -30,8 +30,8 @@ Backends:
 Mixed precision (``plane_dtype='bf16'`` through the facade): the EF state
 buffers (q, m, v, g_prev) live in bf16, so packed planes and the gossip
 wire both carry 2 B/element while the master params ``x`` stay f32 exact
-(the plane dtype is derived *per buffer tree* -- see
-:func:`repro.kernels.flatten.derived_plane_dtype`).  Every fused kernel
+(each leaf's plane keeps that leaf's dtype -- see
+:func:`repro.kernels.flatten.plane_apply`).  Every fused kernel
 still accumulates in f32 inside the block; the writeback to a bf16 buffer
 goes through the stochastic-rounding cast (:mod:`repro.kernels.sr_cast`)
 so the EF drift stays unbiased, with the SR key split off the round key
@@ -39,14 +39,12 @@ so the EF drift stays unbiased, with the SR key split off the round key
 streams are bit-identical to the pre-mixed-precision code.  The push-sum
 weight plane stays f32-exact on every path.
 
-Sharding: for pure data/agent-sharded states (every buffer
-P(agents, None, ...)) the flat plane is sharded along its row axis and the
-in-jit pack is reshard-free.  When the engine is built with ``mesh`` +
-``leaf_specs`` that carry model axes (tensor-parallel layouts), the pallas
-path switches to *per-shard planes*: pack -> kernel -> unpack runs inside
-``shard_map`` with those leaf specs, one padded plane per (agent shard x
-model shard), so no buffer is ever all-gathered over the model axis
-(:func:`repro.kernels.flatten.plane_apply`).  ``backend='pallas'`` is
+Sharding: when the engine is built with ``mesh`` + ``leaf_specs`` (every
+layout the launch layer builds), the pallas path runs its kernels inside
+``shard_map`` with those leaf specs, once per (agent shard x model shard)
+and leaf (:func:`repro.kernels.flatten.plane_apply`):
+a Mosaic kernel is one call the SPMD partitioner cannot split, so outside
+``shard_map`` it would run on gathered buffers.  ``backend='pallas'`` is
 therefore safe on every layout the launch layer builds.
 
 Time-varying topologies: the engine's methods take the absolute round index
@@ -195,9 +193,9 @@ class CommRound:
       backend: 'pallas' | 'ref' | 'auto'.
       interpret: Pallas interpret mode; None = auto (True off-TPU).
       mesh / leaf_specs / agent_axes: sharded-layout hooks (the facade
-        ``repro.api.build_engine`` plumbs them from the launch layer).  When
-        ``leaf_specs`` shard a non-agent mesh axis, the pallas path packs
-        per-shard planes inside ``shard_map`` instead of one global plane.
+        ``repro.api.build_engine`` plumbs them from the launch layer).  With
+        both set, the pallas path runs its kernels on per-shard planes
+        inside ``shard_map``.
       overlap: comm/compute overlap.  The PORTER family runs *two* comm
         rounds per step whose exchanges are data-independent (the x-side
         inputs ``(x, q_x)`` are untouched by the v-side update); with
@@ -256,13 +254,11 @@ class CommRound:
     def _kernel_kw(self):
         return {} if self.interpret is None else {"interpret": self.interpret}
 
-    def _sharded_planes(self) -> Optional[FL.ShardedFlatSpec]:
-        """Per-shard plane layout, or None for the single-plane fast path."""
-        if (self.mesh is None or self.leaf_specs is None
-                or not FL.specs_have_model_axes(self.leaf_specs,
-                                                self.agent_axes)):
+    def _plane_mesh(self):
+        """The mesh the kernels run per shard on, else None (one device)."""
+        if self.mesh is None or self.leaf_specs is None:
             return None
-        return FL.sharded_spec(self.mesh, self.leaf_specs)
+        return self.mesh
 
     # -- stochastic-rounding plumbing ---------------------------------------
 
@@ -282,33 +278,34 @@ class CommRound:
         return k_c, k_sr
 
     def _plane_update(self, kfn, trees, sr_key):
-        """Fused 3-output kernel over planes, with SR writeback when asked.
+        """Fused 3-output kernel, leaf by leaf, with SR writeback when asked.
 
-        ``kfn(*planes, out_dtype=...)`` must return three planes whose
+        ``kfn(*leaves, out_dtype=...)`` must return three arrays whose
         destinations are ``trees[:3]`` in order.  With an ``sr_key`` and
         any bf16 destination, the kernel is asked for f32 outputs and each
-        bf16-bound plane is stochastically rounded before unpacking; f32
-        destinations pass through exact.  Under per-shard planes the SR key
-        is folded with every mesh axis index so no two shards reuse bits.
+        bf16-bound output is stochastically rounded; f32 destinations pass
+        through exact.  The SR key is folded with the
+        leaf index, and under per-shard planes with every mesh axis index,
+        so no two planes reuse bits.
         """
-        sharded = self._sharded_planes()
+        mesh = self._plane_mesh()
         needs = [_sr_dtype(t) for t in trees[:3]]
         if sr_key is None or not any(needs):
-            return FL.plane_apply(lambda *p: kfn(*p), trees, 3, sharded)
+            return FL.plane_apply(lambda _, *ls: kfn(*ls), trees, 3, mesh,
+                                  self.leaf_specs)
         kw = self._kernel_kw()
-        axis_names = (tuple(sharded.mesh.axis_names)
-                      if sharded is not None else ())
+        axis_names = tuple(mesh.axis_names) if mesh is not None else ()
 
-        def kernel(*planes):
-            outs = kfn(*planes, out_dtype=jnp.float32)
-            key = sr_key
+        def kernel(leaf, *leaves):
+            outs = kfn(*leaves, out_dtype=jnp.float32)
+            key = jax.random.fold_in(sr_key, leaf)
             for ax in axis_names:
                 key = jax.random.fold_in(key, jax.lax.axis_index(ax))
             keys = jax.random.split(key, 3)
             return tuple(ops.sr_cast(o, keys[i], **kw) if needs[i] else o
                          for i, o in enumerate(outs))
 
-        return FL.plane_apply(kernel, trees, 3, sharded)
+        return FL.plane_apply(kernel, trees, 3, mesh, self.leaf_specs)
 
     @staticmethod
     def _sr_writeback(tree_f32, like, key):

@@ -60,8 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from . import wire_formats as WF
 from .mixing import Topology, TopologySchedule
 # packed wire format selection window: single source of truth is
@@ -351,13 +349,13 @@ def make_ring_mixer(w, mesh: Mesh,
                 raise ValueError("time-varying ring mixer needs the round "
                                  "index (pass t=state.step)")
             b = _entry(bands_j, t)  # (3,) replicated, traced per round
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda tr, bb: jax.tree_util.tree_map(
                     lambda l: local(l, bb[0], bb[1], bb[2]), tr),
                 mesh=mesh, in_specs=(specs, P()), out_specs=specs,
                 check_vma=False)
             return fn(tree, b)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda tr: jax.tree_util.tree_map(
                 lambda l: local(l, w_self, w_prev, w_next), tr),
             mesh=mesh, in_specs=(specs,), out_specs=specs,
@@ -417,9 +415,9 @@ def make_ring_mixer(w, mesh: Mesh,
             rest = [local(l, bb[0], bb[1], bb[2]) for l in lvs[1:]]
             return [out0] + rest, w_m
 
-        fn = shard_map(run, mesh=mesh,
-                       in_specs=(spec_leaves, w_spec, P()),
-                       out_specs=(spec_leaves, w_spec), check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh,
+                           in_specs=(spec_leaves, w_spec, P()),
+                           out_specs=(spec_leaves, w_spec), check_vma=False)
         outs, w_m = fn(leaves, wvec, b)
         return treedef.unflatten(outs), w_m
 
@@ -538,9 +536,9 @@ def make_packed_mixer(w, mesh: Mesh, frac: float,
             specs = jax.tree_util.tree_map(
                 lambda l: P(axes if len(axes) > 1 else axes[0],
                             *([None] * (l.ndim - 1))), tree)
-        fn = shard_map(run, mesh=mesh,
-                       in_specs=(specs, P()), out_specs=specs,
-                       check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh,
+                           in_specs=(specs, P()), out_specs=specs,
+                           check_vma=False)
         return fn(tree, w_rows)
 
     mix.time_varying = time_varying
@@ -750,10 +748,10 @@ def make_ring_codec_mixer(w, mesh: Mesh, codec: WF.WireFormat,
                     for j, l in enumerate(lvs)]
             return [o[0] for o in outs], [o[1] for o in outs]
 
-        fn = shard_map(run, mesh=mesh,
-                       in_specs=(spec_leaves, P(), P()),
-                       out_specs=(spec_leaves, spec_leaves),
-                       check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh,
+                           in_specs=(spec_leaves, P(), P()),
+                           out_specs=(spec_leaves, spec_leaves),
+                           check_vma=False)
         cs, wcs = fn(leaves, keys, b)
         return treedef.unflatten(cs), treedef.unflatten(wcs)
 
@@ -833,10 +831,11 @@ def make_ring_codec_mixer(w, mesh: Mesh, codec: WF.WireFormat,
             return ([c0] + [o[0] for o in rest],
                     [wc0] + [o[1] for o in rest], cw, wcw)
 
-        fn = shard_map(run, mesh=mesh,
-                       in_specs=(spec_leaves, w_spec, P(), P()),
-                       out_specs=(spec_leaves, spec_leaves, w_spec, w_spec),
-                       check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh,
+                           in_specs=(spec_leaves, w_spec, P(), P()),
+                           out_specs=(spec_leaves, spec_leaves, w_spec,
+                                      w_spec),
+                           check_vma=False)
         cs, wcs, cw, wcw = fn(leaves, dw, keys, b)
         return (treedef.unflatten(cs), treedef.unflatten(wcs),
                 cw.astype(dw.dtype), wcw.astype(dw.dtype))
@@ -921,10 +920,10 @@ def make_packed_codec_mixer(w, mesh: Mesh, codec: WF.WireFormat,
                     for j, l in enumerate(lvs)]
             return [o[0] for o in outs], [o[1] for o in outs]
 
-        fn = shard_map(run, mesh=mesh,
-                       in_specs=(spec_leaves, P(), P()),
-                       out_specs=(spec_leaves, spec_leaves),
-                       check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh,
+                           in_specs=(spec_leaves, P(), P()),
+                           out_specs=(spec_leaves, spec_leaves),
+                           check_vma=False)
         cs, wcs = fn(leaves, w_rows, keys)
         return treedef.unflatten(cs), treedef.unflatten(wcs)
 
@@ -985,10 +984,11 @@ def make_packed_codec_mixer(w, mesh: Mesh, codec: WF.WireFormat,
             return ([c0] + [o[0] for o in rest],
                     [wc0] + [o[1] for o in rest], cw, wcw)
 
-        fn = shard_map(run, mesh=mesh,
-                       in_specs=(spec_leaves, w_spec, P(), P()),
-                       out_specs=(spec_leaves, spec_leaves, w_spec, w_spec),
-                       check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh,
+                           in_specs=(spec_leaves, w_spec, P(), P()),
+                           out_specs=(spec_leaves, spec_leaves, w_spec,
+                                      w_spec),
+                           check_vma=False)
         cs, wcs, cw, wcw = fn(leaves, dw, w_rows, keys)
         return (treedef.unflatten(cs), treedef.unflatten(wcs),
                 cw.astype(dw.dtype), wcw.astype(dw.dtype))

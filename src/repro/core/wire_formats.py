@@ -123,7 +123,7 @@ def qsgd_window_omega(levels: int) -> float:
 # Shared selection threshold (used verbatim inside the Pallas kernels)
 # ---------------------------------------------------------------------------
 
-def bisect_threshold(a: jax.Array, k) -> jax.Array:
+def bisect_threshold(a: jax.Array, k, axis=None) -> jax.Array:
     """Threshold keeping >= k of the values in ``a`` via value bisection.
 
     ``a``: non-negative magnitudes (any shape, reduced globally).  Returns
@@ -132,7 +132,25 @@ def bisect_threshold(a: jax.Array, k) -> jax.Array:
     pass, which is the TPU replacement for sort/radix-select.  Pure jnp, so
     it runs identically inside a Pallas kernel body, under vmap (per-row
     thresholds), and in the jnp reference codecs.
+
+    ``axis``: reduce along that axis only, keeping it as size 1 -- one
+    threshold per row, the same halvings as a vmap over rows, written with
+    selects so a TPU kernel can run it on a block of rows.
     """
+    if axis is not None:
+        hi = jnp.max(a, axis=axis, keepdims=True)
+
+        def rows(_, carry):
+            lo, hi = carry
+            mid = 0.5 * (lo + hi)
+            take = jnp.sum((a >= mid).astype(jnp.int32), axis=axis,
+                           keepdims=True) >= k
+            return jnp.where(take, mid, lo), jnp.where(take, hi, mid)
+
+        lo, _ = jax.lax.fori_loop(0, N_BISECT_ITERS, rows,
+                                  (jnp.zeros_like(hi), hi))
+        return lo
+
     hi = jnp.max(a)
     lo = jnp.zeros_like(hi)
 

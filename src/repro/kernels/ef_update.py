@@ -18,11 +18,14 @@ This kernel fuses the V-side (``ef_track``):   q+=c; m+=wc; v = v + gamma*
 gradient terms swapped for -eta*v.  ``ef_gossip`` is the two-term tail of
 the same family (q+=c; m+=wc; y = y + gamma*(m-q)) and serves the
 CHOCO-SGD / SoteriaFL compressed-gossip updates through the comm-round
-engine (core/comm_round.py).  Tiles: (8, 1024) VPU blocks; callers feed
-the flat plane layout of kernels/flatten.py so one launch covers every
-(agent, leaf) pair.
+engine (core/comm_round.py), one launch per state leaf over all agents
+(kernels/flatten.py).  :func:`grid_call` grids a kernel over the leaf as it
+lies in memory -- its last two dims tiled, the leading ones merged, which
+costs no copy on the TPU.  Flattening a leaf into padded ``(tiles, 8*1024)``
+planes first, as the kernels did, is a relayout copy of every operand.
+The scalars ride in SMEM.
 
-Mixed precision: inputs may arrive as bf16 planes (2 B/element resident
+Mixed precision: inputs may arrive as bf16 buffers (2 B/element resident
 state); every kernel upcasts to f32 *inside* the block, accumulates in f32,
 and writes each output in the dtype of its corresponding state plane
 (q/m/x/v/y), so an f32 master-param plane never narrows just because the EF
@@ -34,25 +37,65 @@ drift unbiased instead of round-to-nearest biased.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LANE = 1024
-TILE = 8 * LANE
+LANE_BLOCK = 2048            # lanes per block when the last dim is wider
+BLOCK_ELEMS = 64 * 1024      # elements per block and operand: 256 KiB in f32
 
 
-def _out_shapes(bufs, out_dtype):
-    return [jax.ShapeDtypeStruct(b.shape,
-                                 b.dtype if out_dtype is None else out_dtype)
-            for b in bufs]
+def _view(shape):
+    """``(lead, rows, cols)`` view of an array: the last two dims stay the
+    tiled ones, the leading dims merge (free on the TPU, no relayout)."""
+    if len(shape) < 2:
+        return (1, 1, math.prod(shape))
+    return (math.prod(shape[:-2]), shape[-2], shape[-1])
+
+
+def grid_call(kernel, arrays, scalars, n_out, out_dtype, interpret):
+    """Grid an elementwise ``kernel`` over same-shape ``arrays`` in place.
+
+    The arrays keep their own layout -- no flatten, no pad -- and the grid
+    walks ``(rows, cols)`` blocks of at most ``BLOCK_ELEMS`` elements
+    (multiples of (16, 128), or the whole dim) with partial edge blocks.
+    The first ``n_out`` arrays give the outputs' shapes and dtypes
+    (``out_dtype`` overrides the dtype); ``scalars``: f32 values passed as
+    ``(1, 1)`` SMEM operands after the arrays.
+    """
+    shape = arrays[0].shape
+    if any(a.shape != shape for a in arrays):
+        raise ValueError(f"grid_call needs same-shape operands, got "
+                         f"{[a.shape for a in arrays]}")
+    lead, rows, cols = _view(shape)
+    bc = min(cols, LANE_BLOCK)
+    lanes = -(-bc // 128) * 128          # VMEM pads the lane dim to 128
+    br = min(rows, max(16, BLOCK_ELEMS // lanes // 16 * 16))
+    blk = pl.BlockSpec((None, br, bc), lambda a, i, j: (a, i, j))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    view = (lead, rows, cols)
+    outs = pl.pallas_call(
+        kernel,
+        grid=(lead, pl.cdiv(rows, br), pl.cdiv(cols, bc)),
+        in_specs=[blk] * len(arrays) + [smem] * len(scalars),
+        out_specs=[blk] * n_out,
+        out_shape=[jax.ShapeDtypeStruct(
+            view, a.dtype if out_dtype is None else out_dtype)
+            for a in arrays[:n_out]],
+        interpret=interpret,
+    )(*[a.reshape(view) for a in arrays],
+      *[jnp.asarray(s, jnp.float32).reshape(1, 1) for s in scalars])
+    return [o.reshape(shape) for o in outs]
 
 
 def _track_kernel(q_ref, m_ref, v_ref, c_ref, wc_ref, g_ref, gp_ref,
                   gamma_ref, q_out, m_out, v_out):
     q = q_ref[...].astype(jnp.float32) + c_ref[...].astype(jnp.float32)
     m = m_ref[...].astype(jnp.float32) + wc_ref[...].astype(jnp.float32)
-    gamma = gamma_ref[0]
+    gamma = gamma_ref[0, 0]
     v = (v_ref[...].astype(jnp.float32) + gamma * (m - q)
          + g_ref[...].astype(jnp.float32) - gp_ref[...].astype(jnp.float32))
     q_out[...] = q.astype(q_out.dtype)
@@ -62,26 +105,17 @@ def _track_kernel(q_ref, m_ref, v_ref, c_ref, wc_ref, g_ref, gp_ref,
 
 def ef_track(q, m, v, c, wc, g, gp, gamma, interpret: bool = False,
              out_dtype=None):
-    """(q,m,v) update of Algorithm 1 lines 11-12.  All inputs (tiles, TILE)."""
-    tiles = q.shape[0]
-    blk = pl.BlockSpec((1, TILE), lambda i: (i, 0))
-    scl = pl.BlockSpec((1,), lambda i: (0,))
-    return pl.pallas_call(
-        _track_kernel,
-        grid=(tiles,),
-        in_specs=[blk] * 7 + [scl],
-        out_specs=[blk] * 3,
-        out_shape=_out_shapes((q, m, v), out_dtype),
-        interpret=interpret,
-    )(q, m, v, c, wc, g, gp, jnp.asarray(gamma, jnp.float32).reshape(1))
+    """(q,m,v) update of Algorithm 1 lines 11-12.  Same-shape inputs."""
+    return grid_call(_track_kernel, (q, m, v, c, wc, g, gp), (gamma,), 3,
+                       out_dtype, interpret)
 
 
 def _step_kernel(q_ref, m_ref, x_ref, c_ref, wc_ref, v_ref,
                  gamma_ref, eta_ref, q_out, m_out, x_out):
     q = q_ref[...].astype(jnp.float32) + c_ref[...].astype(jnp.float32)
     m = m_ref[...].astype(jnp.float32) + wc_ref[...].astype(jnp.float32)
-    x = (x_ref[...].astype(jnp.float32) + gamma_ref[0] * (m - q)
-         - eta_ref[0] * v_ref[...].astype(jnp.float32))
+    x = (x_ref[...].astype(jnp.float32) + gamma_ref[0, 0] * (m - q)
+         - eta_ref[0, 0] * v_ref[...].astype(jnp.float32))
     q_out[...] = q.astype(q_out.dtype)
     m_out[...] = m.astype(m_out.dtype)
     x_out[...] = x.astype(x_out.dtype)
@@ -89,29 +123,19 @@ def _step_kernel(q_ref, m_ref, x_ref, c_ref, wc_ref, v_ref,
 
 def ef_step(q, m, x, c, wc, v, gamma, eta, interpret: bool = False,
             out_dtype=None):
-    """(q,m,x) update of Algorithm 1 lines 13-14.  All inputs (tiles, TILE)."""
-    tiles = q.shape[0]
-    blk = pl.BlockSpec((1, TILE), lambda i: (i, 0))
-    scl = pl.BlockSpec((1,), lambda i: (0,))
-    return pl.pallas_call(
-        _step_kernel,
-        grid=(tiles,),
-        in_specs=[blk] * 6 + [scl, scl],
-        out_specs=[blk] * 3,
-        out_shape=_out_shapes((q, m, x), out_dtype),
-        interpret=interpret,
-    )(q, m, x, c, wc, v, jnp.asarray(gamma, jnp.float32).reshape(1),
-      jnp.asarray(eta, jnp.float32).reshape(1))
+    """(q,m,x) update of Algorithm 1 lines 13-14.  Same-shape inputs."""
+    return grid_call(_step_kernel, (q, m, x, c, wc, v), (gamma, eta), 3,
+                       out_dtype, interpret)
 
 
 def _gossip_kernel(q_ref, m_ref, y_ref, c_ref, wc_ref, gamma_ref, scale_ref,
                    q_out, m_out, y_out):
-    scale = scale_ref[0]
+    scale = scale_ref[0, 0]
     q = (q_ref[...].astype(jnp.float32)
          + scale * c_ref[...].astype(jnp.float32))
     m = (m_ref[...].astype(jnp.float32)
          + scale * wc_ref[...].astype(jnp.float32))
-    y = y_ref[...].astype(jnp.float32) + gamma_ref[0] * (m - q)
+    y = y_ref[...].astype(jnp.float32) + gamma_ref[0, 0] * (m - q)
     q_out[...] = q.astype(q_out.dtype)
     m_out[...] = m.astype(m_out.dtype)
     y_out[...] = y.astype(y_out.dtype)
@@ -122,17 +146,7 @@ def ef_gossip(q, m, y, c, wc, gamma, scale=1.0, interpret: bool = False,
     """(q,m,y) CHOCO/Soteria update: q += s*c; m += s*wc; y += gamma*(m-q).
 
     ``scale`` is 1 for CHOCO-SGD and the SoteriaFL shift stepsize alpha for
-    shifted compression.  All tensor inputs (tiles, TILE).
+    shifted compression.  Same-shape tensor inputs.
     """
-    tiles = q.shape[0]
-    blk = pl.BlockSpec((1, TILE), lambda i: (i, 0))
-    scl = pl.BlockSpec((1,), lambda i: (0,))
-    return pl.pallas_call(
-        _gossip_kernel,
-        grid=(tiles,),
-        in_specs=[blk] * 5 + [scl, scl],
-        out_specs=[blk] * 3,
-        out_shape=_out_shapes((q, m, y), out_dtype),
-        interpret=interpret,
-    )(q, m, y, c, wc, jnp.asarray(gamma, jnp.float32).reshape(1),
-      jnp.asarray(scale, jnp.float32).reshape(1))
+    return grid_call(_gossip_kernel, (q, m, y, c, wc), (gamma, scale), 3,
+                       out_dtype, interpret)

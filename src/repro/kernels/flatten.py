@@ -1,277 +1,79 @@
-"""Flat tile-buffer layout: pytree <-> padded ``(tiles, 8*1024)`` planes.
+"""Per-leaf kernel application over agent-stacked pytrees.
 
-The fused error-feedback kernels (:mod:`repro.kernels.ef_update`) operate on
-2-D tile planes whose rows are one ``(8, 1024)`` VPU tile each.  The
-algorithm layer, however, keeps its state as agent-stacked pytrees (leading
-``n_agents`` axis per leaf).  This module is the bridge: it concatenates all
-leaves of a tree into one flat per-agent vector, zero-pads to a tile
-multiple, and exposes the result as a ``(rows * tiles_per_row, TILE)``
-plane the kernels can grid over in a single launch -- one kernel invocation
-covers every (agent, leaf) pair instead of one pallas_call per leaf.
+The fused error-feedback kernels (:mod:`repro.kernels.ef_update`) and the
+stochastic-rounding cast (:mod:`repro.kernels.sr_cast`) grid over any
+array as it lies in memory.  The algorithm layer keeps its state as
+agent-stacked pytrees (leading ``n_agents`` axis per leaf).
+:func:`plane_apply` is the bridge: it runs a kernel once per leaf, over all
+agents of that leaf, and restores each output leaf's dtype.
 
-The plane dtype is a first-class layout parameter: ``FlatSpec.plane_dtype``
-(default f32) is the storage dtype of the packed plane, so a bf16 engine
-ships and keeps 2 B/element planes end to end while the kernels still
-accumulate in f32 internally.  Writebacks to sub-f32 resident buffers go
-through :mod:`repro.kernels.sr_cast` (stochastic rounding) in the engine,
-not here -- pack/unpack themselves use deterministic ``astype``.
-
-Padding correctness is the subtle part: the pad region is zero on the way
-in, whatever the kernel computes there is dropped by :func:`from_planes`,
-and per-leaf dtypes are restored on the way out (the planes carry the
-spec's ``plane_dtype``; the kernels accumulate in f32 internally).
-tests/test_comm_round.py pins this for odd, non-tile-aligned shapes.
+Packing every leaf of a tree into one concatenated, padded plane is what
+this module did first.  At model scale the TPU compiler then needed tens
+of GiB of host memory for the concatenation (one 251M-parameter round did
+not finish compiling within 20 GiB), and every operand paid a relayout
+copy; per-leaf calls compile in seconds and copy nothing.
 
 Time-varying topologies need no plumbing here: the comm-round engine mixes
-in the pytree domain *before* packing, so under a
+in the pytree domain *before* the kernels run, so under a
 :class:`repro.core.mixing.TopologySchedule` the round's ``wc = W_t @ c``
-arrives at :func:`plane_apply` as ordinary data -- the plane layout, the
-kernel grids and the per-shard program are all schedule-invariant (one
-executable per chunk size, exactly as with a static graph).
+arrives at :func:`plane_apply` as ordinary data -- the kernel grids and
+the per-shard program are all schedule-invariant (one executable per chunk
+size, exactly as with a static graph).
 
-Per-shard planes: a single global plane concatenates leaves with *different*
-model-parallel PartitionSpecs, which XLA SPMD can only realize by
-all-gathering every buffer over the model axis on pack and resharding again
-on unpack.  :class:`ShardedFlatSpec` + :func:`plane_apply` instead run the
-pack -> kernel -> unpack pipeline *inside* ``shard_map`` with the engine's
-leaf specs, building one padded ``(tiles, TILE)`` plane per (agent shard x
-model shard).  The fused updates are elementwise, so the per-shard program
-needs no communication at all -- no byte of the plane ever crosses the
-model axis.
+Per-shard planes: a Mosaic kernel is one opaque call that XLA's SPMD
+partitioner cannot split.  Given a mesh, :func:`plane_apply` therefore
+runs the kernels *inside* ``shard_map`` with the engine's leaf specs, so every device works on its own (agent shard x
+model shard) block.  The fused updates are elementwise, so the per-shard
+program needs no communication at all.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Any, NamedTuple, Sequence, Tuple
+from typing import Any, Sequence
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-__all__ = ["LANE", "SUBLANES", "TILE", "FlatSpec", "flat_spec", "to_planes",
-           "from_planes", "derived_plane_dtype", "ShardedFlatSpec",
-           "sharded_spec", "specs_have_model_axes", "plane_apply"]
-
-LANE = 1024
-SUBLANES = 8
-TILE = SUBLANES * LANE  # elements per (8, 1024) f32 VPU tile
-
-
-class FlatSpec(NamedTuple):
-    """Static description of a tree's flat layout (per row).
-
-    ``rows`` is the leading (agent) axis size, or 0 for an unstacked tree;
-    ``shapes``/``dtypes``/``sizes`` describe each leaf *without* the row
-    axis; ``d`` is the per-row element count and ``tiles`` the number of
-    TILE-sized rows of the plane each logical row occupies;
-    ``plane_dtype`` is the storage dtype of the packed plane (f32 or bf16 --
-    the trailing default keeps pre-plane_dtype positional construction
-    working).
-    """
-
-    treedef: Any
-    shapes: Tuple[Tuple[int, ...], ...]
-    dtypes: Tuple[Any, ...]
-    sizes: Tuple[int, ...]
-    rows: int
-    d: int
-    tiles: int
-    plane_dtype: Any = jnp.float32
-
-    @property
-    def padded(self) -> int:
-        return self.tiles * TILE
-
-    @property
-    def plane_shape(self) -> Tuple[int, int]:
-        n = max(self.rows, 1)
-        return (n * self.tiles, TILE)
-
-
-def derived_plane_dtype(tree) -> Any:
-    """Narrowest lossless storage dtype for ``tree``'s packed plane.
-
-    The promotion of all leaf dtypes: an all-bf16 buffer packs as a
-    2 B/element bf16 plane, an f32 buffer (or a mixed bf16+f32 tree) packs
-    as f32.  This is what keeps the f32 master params exact while the EF
-    planes around them ride at half width.
-    """
-    leaves = jax.tree_util.tree_leaves(tree)
-    if not leaves:
-        raise ValueError("cannot derive a plane dtype for an empty pytree")
-    return jnp.result_type(*[l.dtype for l in leaves])
-
-
-def flat_spec(tree, stacked: bool = True,
-              plane_dtype: Any = None) -> FlatSpec:
-    """Compute the flat layout of ``tree`` (leaves may be ShapeDtypeStructs).
-
-    stacked: leaves carry a leading agent axis (must agree across leaves),
-    which becomes ``spec.rows``; the per-row vector concatenates the
-    remaining dims of every leaf in tree-flatten order.
-
-    plane_dtype: storage dtype of the packed plane; ``None`` (default)
-    derives it from the tree via :func:`derived_plane_dtype`, so f32 trees
-    keep their historical f32 planes and bf16 buffers pack at 2 B/element.
-    """
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    if not leaves:
-        raise ValueError("cannot flatten an empty pytree")
-    if stacked:
-        rows = leaves[0].shape[0]
-        for l in leaves:
-            if l.ndim < 1 or l.shape[0] != rows:
-                raise ValueError(
-                    "stacked flatten needs a shared leading agent axis; got "
-                    f"shapes {[tuple(x.shape) for x in leaves]}")
-        shapes = tuple(tuple(l.shape[1:]) for l in leaves)
-    else:
-        rows = 0
-        shapes = tuple(tuple(l.shape) for l in leaves)
-    sizes = tuple(math.prod(s) if s else 1 for s in shapes)
-    d = sum(sizes)
-    tiles = -(-d // TILE)
-    if plane_dtype is None:
-        plane_dtype = jnp.result_type(*[l.dtype for l in leaves])
-    return FlatSpec(treedef=treedef, shapes=shapes,
-                    dtypes=tuple(l.dtype for l in leaves), sizes=sizes,
-                    rows=rows, d=d, tiles=tiles,
-                    plane_dtype=jnp.dtype(plane_dtype))
-
-
-def to_planes(tree, spec: FlatSpec) -> jax.Array:
-    """Pack ``tree`` into a ``spec.plane_dtype`` plane of ``plane_shape``.
-
-    The tree must match ``spec`` structurally; its leaves may have any
-    floating dtype (cast to the plane dtype here, restored by
-    :func:`from_planes`).
-    """
-    pdt = spec.plane_dtype
-    leaves = jax.tree_util.tree_leaves(tree)
-    if spec.rows:
-        parts = [l.reshape(l.shape[0], -1).astype(pdt) for l in leaves]
-        flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-        flat = jnp.pad(flat, ((0, 0), (0, spec.padded - spec.d)))
-        return flat.reshape(spec.rows * spec.tiles, TILE)
-    parts = [l.reshape(-1).astype(pdt) for l in leaves]
-    flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-    flat = jnp.pad(flat, (0, spec.padded - spec.d))
-    return flat.reshape(spec.tiles, TILE)
-
-
-def from_planes(planes: jax.Array, spec: FlatSpec):
-    """Invert :func:`to_planes`: drop padding, split leaves, restore dtypes."""
-    if spec.rows:
-        flat = planes.reshape(spec.rows, spec.padded)[:, :spec.d]
-        offs, out = 0, []
-        for shape, dtype, size in zip(spec.shapes, spec.dtypes, spec.sizes):
-            leaf = flat[:, offs:offs + size]
-            out.append(leaf.reshape((spec.rows,) + shape).astype(dtype))
-            offs += size
-        return spec.treedef.unflatten(out)
-    flat = planes.reshape(-1)[:spec.d]
-    offs, out = 0, []
-    for shape, dtype, size in zip(spec.shapes, spec.dtypes, spec.sizes):
-        out.append(flat[offs:offs + size].reshape(shape).astype(dtype))
-        offs += size
-    return spec.treedef.unflatten(out)
-
-
-# ---------------------------------------------------------------------------
-# per-shard planes: pack/kernel/unpack inside shard_map
-# ---------------------------------------------------------------------------
-
-class ShardedFlatSpec(NamedTuple):
-    """Static description of the *per-shard* flat layout.
-
-    Unlike :class:`FlatSpec`, the tile counts are not recorded here: each
-    device derives its own local :class:`FlatSpec` from its shard's shapes
-    at trace time inside ``shard_map`` (every shard of an evenly-sharded
-    tree sees the same local shapes, so the derived layout is identical
-    across devices).  What this spec pins down is *where* the planes live:
-    the mesh and the per-leaf PartitionSpecs the pack/unpack must respect,
-    plus the storage dtype of every per-shard plane.
-    """
-
-    mesh: Any
-    leaf_specs: Any               # pytree of PartitionSpec, agent axis first
-    plane_dtype: Any = None       # None: derive per tree from leaf dtypes
-
-
-def specs_have_model_axes(leaf_specs,
-                          agent_axes: Sequence[str] = ("data",)) -> bool:
-    """True when any leaf spec shards a non-agent (model) mesh axis.
-
-    Pure agent sharding (every leaf ``P(agents, None, ...)``) keeps the
-    single global plane shardable along its row axis, so the in-jit pack is
-    already reshard-free there; only model axes force per-shard planes.
-    """
-    agent = set(agent_axes)
-    for s in jax.tree_util.tree_leaves(
-            leaf_specs, is_leaf=lambda x: isinstance(x, P)):
-        if not isinstance(s, P):
-            continue
-        for entry in tuple(s):
-            if entry is None:
-                continue
-            names = entry if isinstance(entry, tuple) else (entry,)
-            if any(n not in agent for n in names):
-                return True
-    return False
-
-
-def sharded_spec(mesh, leaf_specs,
-                 plane_dtype: Any = None) -> ShardedFlatSpec:
-    """Pin the per-shard plane layout for ``plane_apply``."""
-    if mesh is None or leaf_specs is None:
-        raise ValueError("per-shard planes need both a mesh and leaf_specs")
-    return ShardedFlatSpec(
-        mesh=mesh, leaf_specs=leaf_specs,
-        plane_dtype=None if plane_dtype is None else jnp.dtype(plane_dtype))
+__all__ = ["plane_apply"]
 
 
 def plane_apply(kernel, trees: Sequence[Any], n_out: int,
-                sharded: "ShardedFlatSpec | None" = None,
-                plane_dtype: Any = None):
-    """Run ``kernel`` over the flat planes of ``trees``.
+                mesh=None, leaf_specs=None):
+    """Run ``kernel`` over ``trees``, one leaf at a time.
 
-    kernel: ``(plane, ...) -> (plane, ...)`` over same-layout tile planes
-    (``n_out`` outputs); ``trees``: same-structure agent-stacked pytrees.
-    Output ``i`` is restored with the leaf dtypes of ``trees[i]`` -- the
-    engine's update methods return (a permutation of) their first ``n_out``
-    input buffers, and under mixed precision those buffers legitimately
-    differ in dtype (f32 master params next to bf16 EF planes), so a single
-    shared spec would silently downcast the master copy.
+    kernel: ``(j, leaf, ...) -> (leaf, ...)`` over same-shape agent-stacked
+    leaves (``n_out`` outputs), where ``j`` is the leaf's index in
+    tree-flatten order (the engine folds it into its stochastic-rounding
+    key); ``trees``: same-structure agent-stacked pytrees.  Output ``i`` is
+    restored with the leaf dtypes of ``trees[i]`` -- the engine's update
+    methods return (a permutation of) their first ``n_out`` input buffers,
+    and under mixed precision those buffers legitimately differ in dtype
+    (f32 master params next to bf16 EF planes), so the master copy is never
+    downcast.
 
-    plane_dtype: storage dtype of the packed planes; ``None`` (the default,
-    and ``sharded.plane_dtype`` when a sharded spec is given) derives each
-    tree's plane dtype from its own leaves (:func:`derived_plane_dtype`),
-    so a bf16 EF buffer packs at 2 B/element while the f32 master param
-    tree beside it keeps an exact f32 plane.
-
-    With ``sharded=None`` this is the single-plane path: one global pack,
-    one kernel launch, one unpack.  With a :class:`ShardedFlatSpec` the same
-    three steps run inside ``shard_map`` over ``sharded.mesh``, so every
-    device packs only its local (agent shard x model shard) block and the
-    kernel grid covers one per-shard plane -- no leaf ever crosses the
-    model axis.
+    With ``mesh=None`` the kernels run on the global arrays (one device).
+    With a mesh the same calls run inside ``shard_map`` over it, each device
+    on its local block; ``leaf_specs`` are the per-leaf PartitionSpecs
+    (agent axis first) that inputs and outputs keep.
     """
-    if plane_dtype is None and sharded is not None:
-        plane_dtype = sharded.plane_dtype
+    structs = [jax.tree_util.tree_structure(t) for t in trees]
+    if any(s != structs[0] for s in structs):
+        raise ValueError(f"plane_apply needs same-structure trees, got "
+                         f"{structs}")
 
     def local(*ts):
-        specs = [flat_spec(t, plane_dtype=plane_dtype) for t in ts]
-        outs = kernel(*(to_planes(t, s) for t, s in zip(ts, specs)))
-        return tuple(from_planes(o, specs[i]) for i, o in enumerate(outs))
+        groups = zip(*(jax.tree_util.tree_leaves(t) for t in ts))
+        outs = [[] for _ in range(n_out)]
+        for j, leaves in enumerate(groups):
+            res = kernel(j, *leaves)
+            for i, o in enumerate(res):
+                outs[i].append(o.astype(leaves[i].dtype))
+        return tuple(structs[i].unflatten(outs[i]) for i in range(n_out))
 
-    if sharded is None:
+    if mesh is None:
         return local(*trees)
-
-    from repro.compat import shard_map  # deferred: keep kernels jax-only
-
-    specs = sharded.leaf_specs
-    fn = shard_map(local, mesh=sharded.mesh,
-                   in_specs=(specs,) * len(trees),
-                   out_specs=(specs,) * n_out, check_vma=False)
+    if leaf_specs is None:
+        raise ValueError("per-shard planes need leaf_specs with the mesh")
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(leaf_specs,) * len(trees),
+                       out_specs=(leaf_specs,) * n_out, check_vma=False)
     return fn(*trees)

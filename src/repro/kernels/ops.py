@@ -1,7 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles shape plumbing (flatten -> pad to tile multiples -> 2D tile grid ->
-un-pad) and the interpret switch: on CPU (this container) kernels execute in
+un-pad, where a kernel needs it; the EF and SR kernels grid over an array
+as it lies) and the interpret switch: on CPU kernels execute in
 ``interpret=True`` mode, which runs the kernel body in Python/XLA-CPU and is
 what the allclose tests validate; on TPU the same code lowers to Mosaic.
 
@@ -9,7 +10,7 @@ The ef_* wrappers are additionally shard_map-safe: the comm-round engine's
 per-shard plane path (:func:`repro.kernels.flatten.plane_apply`) invokes
 them once *per (agent shard x model shard)* inside ``shard_map``, so they
 must stay shape-polymorphic and free of global-device assumptions (no mesh
-queries, no collectives) -- each call sees only its shard's plane.
+queries, no collectives) -- each call sees only its shard's block.
 
 Use ``repro.kernels.ops`` from the algorithm layer; never call the raw
 kernels directly.
@@ -122,42 +123,25 @@ def wire_qsgd_unpack(word: jax.Array, scale: jax.Array, levels: int,
     return _wp.qsgd_unpack(word, scale, levels, interpret=interpret)
 
 
-def _tile_args(arrays, tile):
-    flat = [a.reshape(-1) for a in arrays]
-    d = flat[0].shape[0]
-    out = []
-    for f in flat:
-        assert f.shape[0] == d, "ef kernels need same-size operands"
-        x2d, _ = _pad_2d(f, tile)
-        out.append(x2d)
-    return out, d
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sr_cast(x: jax.Array, key: jax.Array,
             interpret: bool | None = None) -> jax.Array:
     """Stochastic-rounding f32 -> bf16 cast over an arbitrary-shape array.
 
-    Random bits come from ``key`` outside the kernel, so this and
-    :func:`sr_cast_ref` round bit-identically for the same key (the pattern
-    wire_qsgd_pack uses for its dither noise).
+    Random bits come from ``key`` outside the kernel, in ``x``'s own shape,
+    so this and :func:`sr_cast_ref` round bit-identically for the same key
+    (the pattern wire_qsgd_pack uses for its dither noise).
     """
     interpret = default_interpret() if interpret is None else interpret
-    shape = x.shape
-    x2d, d = _pad_2d(x.reshape(-1).astype(jnp.float32), _srk.TILE)
-    bits = jax.random.bits(key, x2d.shape, jnp.uint32)
-    y2d = _srk.sr_cast(x2d, bits, interpret=interpret)
-    return y2d.reshape(-1)[:d].reshape(shape)
+    bits = jax.random.bits(key, x.shape, jnp.uint32)
+    return _srk.sr_cast(x.astype(jnp.float32), bits, interpret=interpret)
 
 
 @jax.jit
 def sr_cast_ref(x: jax.Array, key: jax.Array) -> jax.Array:
-    """jnp reference for :func:`sr_cast` (same pad + bits draw, no pallas)."""
-    shape = x.shape
-    x2d, d = _pad_2d(x.reshape(-1).astype(jnp.float32), _srk.TILE)
-    bits = jax.random.bits(key, x2d.shape, jnp.uint32)
-    y2d = _srk.sr_cast_ref(x2d, bits)
-    return y2d.reshape(-1)[:d].reshape(shape)
+    """jnp reference for :func:`sr_cast` (same bits draw, no pallas)."""
+    bits = jax.random.bits(key, x.shape, jnp.uint32)
+    return _srk.sr_cast_ref(x.astype(jnp.float32), bits)
 
 
 @jax.jit
@@ -191,13 +175,8 @@ def ef_track(q, m, v, c, wc, g, gp, gamma, interpret: bool | None = None,
     ``None`` keeps each output in its state operand's dtype.
     """
     interpret = default_interpret() if interpret is None else interpret
-    shape = q.shape
-    (q2, m2, v2, c2, wc2, g2, gp2), d = _tile_args(
-        (q, m, v, c, wc, g, gp), _ef.TILE)
-    qo, mo, vo = _ef.ef_track(q2, m2, v2, c2, wc2, g2, gp2, gamma,
-                              interpret=interpret, out_dtype=out_dtype)
-    unpad = lambda a: a.reshape(-1)[:d].reshape(shape)
-    return unpad(qo), unpad(mo), unpad(vo)
+    return tuple(_ef.ef_track(q, m, v, c, wc, g, gp, gamma,
+                              interpret=interpret, out_dtype=out_dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))
@@ -205,12 +184,8 @@ def ef_step(q, m, x, c, wc, v, gamma, eta, interpret: bool | None = None,
             out_dtype=None):
     """Fused Algorithm-1 lines 13-14 (q += c; m += wc; x update)."""
     interpret = default_interpret() if interpret is None else interpret
-    shape = q.shape
-    (q2, m2, x2, c2, wc2, v2), d = _tile_args((q, m, x, c, wc, v), _ef.TILE)
-    qo, mo, xo = _ef.ef_step(q2, m2, x2, c2, wc2, v2, gamma, eta,
-                             interpret=interpret, out_dtype=out_dtype)
-    unpad = lambda a: a.reshape(-1)[:d].reshape(shape)
-    return unpad(qo), unpad(mo), unpad(xo)
+    return tuple(_ef.ef_step(q, m, x, c, wc, v, gamma, eta,
+                             interpret=interpret, out_dtype=out_dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))
@@ -218,12 +193,8 @@ def ef_gossip(q, m, y, c, wc, gamma, scale=1.0, interpret: bool | None = None,
               out_dtype=None):
     """Fused CHOCO/Soteria update (q += s*c; m += s*wc; y += gamma*(m-q))."""
     interpret = default_interpret() if interpret is None else interpret
-    shape = q.shape
-    (q2, m2, y2, c2, wc2), d = _tile_args((q, m, y, c, wc), _ef.TILE)
-    qo, mo, yo = _ef.ef_gossip(q2, m2, y2, c2, wc2, gamma, scale,
-                               interpret=interpret, out_dtype=out_dtype)
-    unpad = lambda a: a.reshape(-1)[:d].reshape(shape)
-    return unpad(qo), unpad(mo), unpad(yo)
+    return tuple(_ef.ef_gossip(q, m, y, c, wc, gamma, scale,
+                               interpret=interpret, out_dtype=out_dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -31,10 +31,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-LANE = 1024
-TILE = 8 * LANE
+from .ef_update import grid_call
+
+__all__ = ["sr_cast", "sr_cast_ref"]
+
 
 def _sr_body(vals, bits):
     """Shared f32->bf16 stochastic-rounding arithmetic (jnp ops only).
@@ -53,23 +54,15 @@ def _sr_kernel(x_ref, r_ref, o_ref):
 
 
 def sr_cast(x, bits, interpret: bool = False):
-    """Stochastically round an f32 ``(tiles, TILE)`` plane to bf16.
+    """Stochastically round an f32 array (any shape) to bf16.
 
-    ``bits``: uint32 plane of the same shape (only the low 16 bits of each
+    ``bits``: uint32 array of the same shape (only the low 16 bits of each
     word are used).
     """
     if x.shape != bits.shape:
         raise ValueError(f"sr_cast shape mismatch: {x.shape} vs {bits.shape}")
-    tiles = x.shape[0]
-    blk = pl.BlockSpec((1, TILE), lambda i: (i, 0))
-    return pl.pallas_call(
-        _sr_kernel,
-        grid=(tiles,),
-        in_specs=[blk, blk],
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.bfloat16),
-        interpret=interpret,
-    )(x, bits)
+    return grid_call(_sr_kernel, (x, bits), (), 1, jnp.bfloat16,
+                     interpret)[0]
 
 
 def sr_cast_ref(x, bits):

@@ -7,9 +7,9 @@ Layouts (single source of truth: :mod:`repro.core.wire_formats`):
   (the same routine kernels/block_topk.py zeroes with), then compaction of
   the k survivors into contiguous (bf16 value, index) segments.  TPUs have
   no VMEM scatter, so compaction is a one-hot matmul: rank each survivor by
-  cumulative count (first k in index order; threshold ties beyond k drop
-  deterministically) and contract the window against the (BLOCK, k)
-  rank-indicator -- an MXU pass instead of a serial gather.
+  its running count (first k in index order; threshold ties beyond k drop
+  deterministically) and contract the window against the rank indicator --
+  an MXU pass instead of a serial gather.
 
 * qsgd    -- per-window stochastic quantization to codes in [0, levels]
   plus a sign bit, then shift/OR of ``32 // bits`` fields per uint32 word.
@@ -18,9 +18,25 @@ Layouts (single source of truth: :mod:`repro.core.wire_formats`):
   reference (wire_formats.qsgd_pack_ref) is bit-comparable.
 
 Unpack kernels invert each layout on the receiver: top-k scatters via the
-transpose one-hot matmul, qsgd shifts/masks the fields back out.  All four
-kernels run per (1, BLOCK) grid row like block_topk; index arithmetic stays
-in f32 (positions < 2048 are exactly representable) until the final cast.
+transposed one-hot matmul, qsgd shifts/masks the fields back out.
+
+Blocking, shaped by what the TPU lowering accepts:
+
+* top-k packs ``ROWS`` windows per grid step, each seen as a
+  ``(16, 128)`` tile stack (a free reshape of the window).  The running
+  count is a triangular matmul within each 128-lane chunk plus a matmul
+  over the chunk sums before it, and the one-hot is built one 128-lane
+  chunk at a time as a ``(k_pad, 128)`` slab, so its VMEM stays bounded at
+  any k (a whole window's ``(PACK_BLOCK, k)`` one-hot is 4 MiB at k = 512).
+  The slot axis is padded to ``k_pad``, a multiple of 128, and trimmed
+  outside the kernel.  Unpack mirrors it per window row.  Every matmul runs
+  at HIGHEST precision, so counts, values and positions are exact; rows
+  are merged into the output block by select, since the lowering stores
+  whole blocks only.
+* qsgd works on the ``(epw, windows, words)`` transpose of the windows, in
+  which the fields that share a word sit on the leading axis: packing is an
+  OR over that axis, with no lane shuffles.  The per-window norm comes in
+  as an operand, computed outside by the reference's own expression.
 
 The jit'd public wrappers live in :mod:`repro.kernels.ops`
 (wire_topk_pack / wire_topk_unpack / wire_qsgd_pack / wire_qsgd_unpack).
@@ -41,29 +57,66 @@ from repro.core.wire_formats import (PACK_BLOCK, TOPK_VALUE_DTYPE,
                                      qsgd_window_omega)
 
 BLOCK = PACK_BLOCK
+CHUNK = 128                  # lanes per one-hot slab
+ROWS = 8                     # top-k windows per grid step
+QSGD_ROWS = 32               # qsgd windows per grid step
+_HI = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))   # contract the last dims: a @ b.T
+
+
+def _pad_slots(k: int) -> int:
+    return -(-k // CHUNK) * CHUNK
 
 
 # ---------------------------------------------------------------------------
 # top-k: fused select + compact
 # ---------------------------------------------------------------------------
 
-def _topk_pack_kernel(x_ref, k_ref, v_ref, i_ref, *, k: int):
-    x = x_ref[...].astype(jnp.float32)                    # (1, BLOCK)
-    a = jnp.abs(x)
-    thresh = bisect_threshold(a, k_ref[0])                # shared selection
-    keep = (a >= thresh).astype(jnp.float32)
-    rank = jnp.cumsum(keep, axis=1) - 1.0                 # (1, BLOCK)
-    sel = keep * (rank < k).astype(jnp.float32)           # first k, by index
-    # one-hot compaction: onehot[e, r] = 1 iff element e lands in slot r
-    slot = jax.lax.broadcasted_iota(jnp.float32, (BLOCK, k), 1)
-    onehot = sel.reshape(BLOCK, 1) * (rank.reshape(BLOCK, 1) == slot
-                                      ).astype(jnp.float32)
-    v_ref[...] = jnp.dot(x, onehot,
-                         preferred_element_type=jnp.float32
-                         ).astype(v_ref.dtype)            # (1, k)
-    pos = jax.lax.broadcasted_iota(jnp.float32, (BLOCK, k), 0)
-    i_ref[...] = jnp.sum(pos * onehot, axis=0,
-                         keepdims=True).astype(jnp.int32)  # (1, k)
+def _topk_pack_kernel(x_ref, v_ref, i_ref, *, k: int):
+    r, kp = v_ref.shape
+    n_chunks = BLOCK // CHUNK
+    iota = jax.lax.broadcasted_iota
+    # inclusive prefix count: within a 128-lane chunk, and over the chunks
+    # before it (0/1 operands, counts <= 2048: exact in f32)
+    tri = (iota(jnp.int32, (CHUNK, CHUNK), 0)
+           <= iota(jnp.int32, (CHUNK, CHUNK), 1)).astype(jnp.float32)
+    before = (iota(jnp.int32, (n_chunks, n_chunks), 1)
+              < iota(jnp.int32, (n_chunks, n_chunks), 0)).astype(jnp.float32)
+    slot = iota(jnp.int32, (kp, CHUNK), 0).astype(jnp.float32)
+    lane = iota(jnp.int32, (1, CHUNK), 1).astype(jnp.float32)
+    row = iota(jnp.int32, (r, kp), 0)
+
+    def window(w, acc):
+        x = x_ref[w].astype(jnp.float32)                  # (chunks, CHUNK)
+        a = jnp.abs(x)
+        thresh = bisect_threshold(a, k, axis=(0, 1))      # shared selection
+        keep = (a >= thresh).astype(jnp.float32)
+        rank = (jnp.dot(keep, tri, precision=_HI,
+                        preferred_element_type=jnp.float32)
+                + jnp.dot(before, jnp.broadcast_to(
+                    jnp.sum(keep, axis=1, keepdims=True), keep.shape),
+                    precision=_HI, preferred_element_type=jnp.float32))
+        # slot of each kept element, -1 for the rest (first k, by index)
+        dest = jnp.where((keep > 0) & (rank <= k), rank - 1.0, -1.0)
+        vals = jnp.zeros((1, kp), jnp.float32)
+        pos = jnp.zeros((1, kp), jnp.float32)
+        for c in range(n_chunks):
+            # onehot[s, e] = 1 iff element e of chunk c lands in slot s
+            onehot = (slot == dest[c:c + 1]).astype(jnp.float32)
+            vals += jax.lax.dot_general(x[c:c + 1], onehot, _NT,
+                                        precision=_HI,
+                                        preferred_element_type=jnp.float32)
+            pos += jax.lax.dot_general(lane + float(c * CHUNK), onehot, _NT,
+                                       precision=_HI,
+                                       preferred_element_type=jnp.float32)
+        # rows are merged by select: the lowering stores whole blocks only
+        return (jnp.where(row == w, vals, acc[0]),
+                jnp.where(row == w, pos, acc[1]))
+
+    zeros = jnp.zeros((r, kp), jnp.float32)
+    vals, pos = jax.lax.fori_loop(0, r, window, (zeros, zeros))
+    v_ref[...] = vals
+    i_ref[...] = pos.astype(jnp.int32)
 
 
 def topk_pack(x2d: jax.Array, k: int, interpret: bool = False):
@@ -74,74 +127,94 @@ def topk_pack(x2d: jax.Array, k: int, interpret: bool = False):
     layer narrows them to uint16 (wire_formats.TOPK_INDEX_DTYPE).
     """
     blocks = x2d.shape[0]
-    blk = pl.BlockSpec((1, BLOCK), lambda i: (i, 0))
-    out = pl.BlockSpec((1, k), lambda i: (i, 0))
-    return pl.pallas_call(
+    kp = _pad_slots(k)
+    r = min(ROWS, blocks)
+    out = pl.BlockSpec((r, kp), lambda i: (i, 0))
+    vals, idx = pl.pallas_call(
         functools.partial(_topk_pack_kernel, k=k),
-        grid=(blocks,),
-        in_specs=[blk, pl.BlockSpec((1,), lambda i: (0,))],
+        grid=(pl.cdiv(blocks, r),),
+        in_specs=[pl.BlockSpec((r, BLOCK // CHUNK, CHUNK),
+                               lambda i: (i, 0, 0))],
         out_specs=(out, out),
-        out_shape=(jax.ShapeDtypeStruct((blocks, k), TOPK_VALUE_DTYPE),
-                   jax.ShapeDtypeStruct((blocks, k), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct((blocks, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((blocks, kp), jnp.int32)),
         interpret=interpret,
-    )(x2d, jnp.full((1,), k, jnp.int32))
+    )(x2d.reshape(blocks, BLOCK // CHUNK, CHUNK))
+    return vals[:, :k].astype(TOPK_VALUE_DTYPE), idx[:, :k]
 
 
-def _topk_unpack_kernel(v_ref, i_ref, o_ref, *, k: int):
-    vals = v_ref[...].astype(jnp.float32)                 # (1, k)
-    idx = i_ref[...].astype(jnp.float32)                  # (1, k)
-    # transpose one-hot scatter: dense[j] = sum_r vals[r] * [idx[r] == j]
-    cols = jax.lax.broadcasted_iota(jnp.float32, (k, BLOCK), 1)
-    onehot = (idx.reshape(k, 1) == cols).astype(jnp.float32)
-    o_ref[...] = jnp.dot(vals, onehot,
-                         preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)            # (1, BLOCK)
+def _topk_unpack_kernel(v_ref, i_ref, o_ref):
+    r, kp = v_ref.shape
+    elem = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, kp), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, CHUNK), 0)
+
+    def window(w, acc):
+        vals = v_ref[pl.ds(w, 1), :]                      # (1, kp) f32
+        idx = i_ref[pl.ds(w, 1), :]                       # (1, kp) int32
+        out = []
+        for c in range(BLOCK // CHUNK):
+            # onehot[e, r] = 1 iff slot r holds element e of this chunk
+            onehot = (elem + c * CHUNK == idx).astype(jnp.float32)
+            dense = jax.lax.dot_general(vals, onehot, _NT, precision=_HI,
+                                        preferred_element_type=jnp.float32)
+            out.append(jnp.where(row == w, dense, acc[c]))
+        return tuple(out)
+
+    zeros = jnp.zeros((r, CHUNK), jnp.float32)
+    chunks = jax.lax.fori_loop(0, r, window,
+                               (zeros,) * (BLOCK // CHUNK))
+    for c, dense in enumerate(chunks):
+        o_ref[:, c * CHUNK:(c + 1) * CHUNK] = dense
 
 
 def topk_unpack(vals: jax.Array, idx: jax.Array,
                 interpret: bool = False) -> jax.Array:
     """(values (blocks, k), int32 indices) -> dense f32 (blocks, BLOCK)."""
     blocks, k = vals.shape
-    blk = pl.BlockSpec((1, k), lambda i: (i, 0))
-    out = pl.BlockSpec((1, BLOCK), lambda i: (i, 0))
+    kp = _pad_slots(k)
+    # padded slots point nowhere (index -1), so they scatter nothing
+    vals = jnp.pad(vals.astype(jnp.float32), ((0, 0), (0, kp - k)))
+    idx = jnp.pad(idx.astype(jnp.int32), ((0, 0), (0, kp - k)),
+                  constant_values=-1)
+    r = min(ROWS, blocks)
+    blk = pl.BlockSpec((r, kp), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(_topk_unpack_kernel, k=k),
-        grid=(blocks,),
+        _topk_unpack_kernel,
+        grid=(pl.cdiv(blocks, r),),
         in_specs=[blk, blk],
-        out_specs=out,
+        out_specs=pl.BlockSpec((r, BLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((blocks, BLOCK), jnp.float32),
         interpret=interpret,
-    )(vals, idx.astype(jnp.int32))
+    )(vals, idx)
 
 
 # ---------------------------------------------------------------------------
-# qsgd: quantize + shift/OR bit-pack
+# qsgd: quantize + shift/OR bit-pack over the (epw, windows, words) view
 # ---------------------------------------------------------------------------
 
-def _qsgd_pack_kernel(x_ref, u_ref, w_ref, s_ref, *, levels: int):
-    bits = qsgd_bits(levels)
+def _to_fields(a: jax.Array, levels: int) -> jax.Array:
+    """(blocks, BLOCK) -> (epw, blocks, words): field e of word j on axis 0.
+
+    The tail of the last word is zero-padded, as in the reference."""
     epw = qsgd_elems_per_word(levels)
     words = qsgd_words_per_window(levels)
-    x = x_ref[...].astype(jnp.float32)                    # (1, BLOCK)
+    a = jnp.pad(a, ((0, 0), (0, words * epw - BLOCK)))
+    return a.reshape(a.shape[0], words, epw).transpose(2, 0, 1)
+
+
+def _qsgd_pack_kernel(x_ref, u_ref, n_ref, w_ref, *, levels: int):
+    bits = qsgd_bits(levels)
+    x = x_ref[...].astype(jnp.float32)                    # (epw, R, words)
     u = u_ref[...].astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(x * x)) + 1e-30
-    y = jnp.abs(x) / norm * levels
+    y = jnp.abs(x) / n_ref[...][None] * levels
     lo = jnp.floor(y)
-    code = (lo + (u < (y - lo))).astype(jnp.uint32)       # [0, levels]
-    sign = (x < 0).astype(jnp.uint32)
-    field = code | (sign << jnp.uint32(bits - 1))         # (1, BLOCK)
-    pad = words * epw - BLOCK
-    if pad:
-        field = jnp.pad(field, ((0, 0), (0, pad)))
-    field = field.reshape(words, epw)
-    word = jnp.zeros((1, words), jnp.uint32)
-    for e in range(epw):                                  # static OR chain
-        word = word | (field[:, e].reshape(1, words)
-                       << jnp.uint32(bits * e))
+    code = (lo + (u < (y - lo))).astype(jnp.int32)        # [0, levels]
+    sign = (x < 0).astype(jnp.int32)
+    field = code | (sign << (bits - 1))
+    word = field[0]
+    for e in range(1, x.shape[0]):                        # static OR chain
+        word = word | (field[e] << (bits * e))
     w_ref[...] = word
-    omega = qsgd_window_omega(levels)
-    s_ref[...] = (norm / (levels * (1.0 + omega))
-                  ).astype(jnp.float32).reshape(1, 1)
 
 
 def qsgd_pack(x2d: jax.Array, noise2d: jax.Array, levels: int,
@@ -153,48 +226,52 @@ def qsgd_pack(x2d: jax.Array, noise2d: jax.Array, levels: int,
     quantize identically.
     """
     blocks = x2d.shape[0]
+    epw = qsgd_elems_per_word(levels)
     words = qsgd_words_per_window(levels)
-    blk = pl.BlockSpec((1, BLOCK), lambda i: (i, 0))
-    return pl.pallas_call(
+    x32 = x2d.astype(jnp.float32)
+    norm = jnp.sqrt(jnp.sum(x32 * x32, axis=1)) + 1e-30      # (blocks,)
+    r = min(QSGD_ROWS, blocks)
+    fields = pl.BlockSpec((epw, r, words), lambda i: (0, i, 0))
+    word = pl.pallas_call(
         functools.partial(_qsgd_pack_kernel, levels=levels),
-        grid=(blocks,),
-        in_specs=[blk, blk],
-        out_specs=(pl.BlockSpec((1, words), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((blocks, words), jnp.uint32),
-                   jax.ShapeDtypeStruct((blocks, 1), jnp.float32)),
+        grid=(pl.cdiv(blocks, r),),
+        in_specs=[fields, fields, pl.BlockSpec((r, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((r, words), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((blocks, words), jnp.int32),
         interpret=interpret,
-    )(x2d, noise2d)
+    )(_to_fields(x32, levels), _to_fields(noise2d, levels), norm[:, None])
+    omega = qsgd_window_omega(levels)
+    scale = (norm / (levels * (1.0 + omega))).astype(jnp.float32)
+    return (jax.lax.bitcast_convert_type(word, jnp.uint32),
+            scale[:, None])
 
 
 def _qsgd_unpack_kernel(w_ref, s_ref, o_ref, *, levels: int):
     bits = qsgd_bits(levels)
-    epw = qsgd_elems_per_word(levels)
-    words = w_ref.shape[-1]
-    word = w_ref[...]                                     # (1, words) u32
-    scale = s_ref[0, 0]
-    mag_mask = jnp.uint32(2 ** (bits - 1) - 1)
-    field_mask = jnp.uint32(2 ** bits - 1)
-    cols = []
-    for e in range(epw):
-        f = (word >> jnp.uint32(bits * e)) & field_mask
+    word = w_ref[...]                                     # (R, words) int32
+    scale = s_ref[...]                                    # (R, 1)
+    mag_mask = 2 ** (bits - 1) - 1
+    field_mask = 2 ** bits - 1
+    for e in range(o_ref.shape[0]):
+        f = (word >> (bits * e)) & field_mask
         code = (f & mag_mask).astype(jnp.float32)
-        sgn = 1.0 - 2.0 * (f >> jnp.uint32(bits - 1)).astype(jnp.float32)
-        cols.append(sgn * code)
-    vals = jnp.stack(cols, axis=2).reshape(1, words * epw)[:, :BLOCK]
-    o_ref[...] = (vals * scale).astype(o_ref.dtype)
+        sgn = 1.0 - 2.0 * (f >> (bits - 1)).astype(jnp.float32)
+        o_ref[e] = (sgn * code * scale).astype(o_ref.dtype)
 
 
 def qsgd_unpack(word: jax.Array, scale: jax.Array, levels: int,
                 interpret: bool = False) -> jax.Array:
     """(uint32 (blocks, W), f32 (blocks, 1)) -> dense f32 (blocks, BLOCK)."""
     blocks, words = word.shape
-    return pl.pallas_call(
+    epw = qsgd_elems_per_word(levels)
+    r = min(QSGD_ROWS, blocks)
+    out = pl.pallas_call(
         functools.partial(_qsgd_unpack_kernel, levels=levels),
-        grid=(blocks,),
-        in_specs=[pl.BlockSpec((1, words), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((blocks, BLOCK), jnp.float32),
+        grid=(pl.cdiv(blocks, r),),
+        in_specs=[pl.BlockSpec((r, words), lambda i: (i, 0)),
+                  pl.BlockSpec((r, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((epw, r, words), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((epw, blocks, words), jnp.float32),
         interpret=interpret,
-    )(word, scale)
+    )(jax.lax.bitcast_convert_type(word, jnp.int32), scale)
+    return out.transpose(1, 2, 0).reshape(blocks, words * epw)[:, :BLOCK]

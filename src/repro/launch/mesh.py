@@ -6,26 +6,48 @@ smoke tests must see 1 CPU device while the dry-run sees 512 forced hosts).
 
 Single pod : (data=16, model=16)            = 256 chips (v5e pod)
 Multi-pod  : (pod=2, data=16, model=16)     = 512 chips
+One host   : (data=n, model=1)              = n chips, one agent per chip
 
 PORTER's decentralized agents live on the *agent axes*: ('data',) single-pod
 (16 agents), ('pod','data') multi-pod (32 agents).  Tensor parallelism for
 each agent's replica lives on 'model'.
+
+Every mesh in the repo is built by :func:`make_mesh`, which names its axes
+``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+``vmap`` over an agent-sharded leaf is refused, and the sharded code here
+relies on the partitioner propagating shardings.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "agent_axes", "n_agents", "HW"]
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh",
+           "agent_axes", "n_agents", "HW"]
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
+
+
+def make_host_mesh(n_agents: int, devices=None):
+    """One agent per chip on ``'data'``; ``'model'`` has size 1, so the
+    model's tensor-parallel specs resolve and every leaf stays whole on
+    its agent's chip."""
+    return make_mesh((n_agents, 1), ("data", "model"), devices=devices)
 
 
 def agent_axes(mesh) -> Tuple[str, ...]:

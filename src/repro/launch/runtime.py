@@ -70,9 +70,12 @@ def _dealias(state):
     seen = set()
 
     def buffer_key(leaf):
+        # every shard's buffer: two objects that share buffers (an aliased
+        # init, sharded or not) must not both be donated
         try:
-            return leaf.unsafe_buffer_pointer()
-        except Exception:  # sharded / committed arrays: object identity
+            return tuple(s.data.unsafe_buffer_pointer()
+                         for s in leaf.addressable_shards)
+        except Exception:  # deleted or not yet materialized: identity
             return id(leaf)
 
     def dedupe(leaf):

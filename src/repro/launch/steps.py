@@ -54,16 +54,14 @@ def make_shard_local_compress(comp, mesh: Mesh, leaf_specs):
         raise ValueError("shard-local compression needs a deterministic "
                          "compressor (top_k / block_top_k)")
 
-    from repro.compat import shard_map
-
     def compress(key, tree):
         del key  # deterministic
 
         def run(t):
             return jax.tree_util.tree_map(lambda l: comp(None, l), t)
 
-        fn = shard_map(run, mesh=mesh, in_specs=(leaf_specs,),
-                       out_specs=leaf_specs, check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh, in_specs=(leaf_specs,),
+                           out_specs=leaf_specs, check_vma=False)
         return fn(tree)
 
     return compress
@@ -174,10 +172,9 @@ def build_train_step(
     shard-local compression and the packed wire format compose with either
     (compression/mixing stay in the pytree domain, only the AXPY chain runs
     over the flat tile planes).  The stacked leaf specs built here flow
-    through ``api.build`` into the engine, so with model-sharded parameter
-    leaves the pallas path packs *per-shard planes* inside shard_map
-    (kernels/flatten.py) -- no pack/unpack reshard, 'pallas' is safe on
-    tensor-parallel layouts.
+    through ``api.build`` into the engine, so the pallas path runs its
+    kernels on *per-shard planes* inside shard_map (kernels/flatten.py) --
+    no pack/unpack reshard, 'pallas' is safe on every layout.
 
     wire: 'dense' ships f32 planes; 'packed_bits' ships the bit-packed
     buffers from ``repro.core.wire_formats`` (bf16+uint16 top-k segments or

@@ -25,6 +25,12 @@ Examples (CPU, ~100M-scale and smoke-scale):
     PYTHONPATH=src python -m repro.launch.train --smoke --algo choco
     PYTHONPATH=src python -m repro.launch.train --arch rwkv6-7b --smoke \
         --algo porter-dp --epsilon 0.1 --steps 30
+
+On one TPU v5e chip, at published widths cut to one layer (the run
+chip_smoke.py makes; the comm round then uses the compiled Pallas kernels):
+    PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
+        --n-layers 1 --agents 2 --batch 2 --seq 512 --plane-dtype bf16 \
+        --steps 4 --chunk 2
 """
 
 from __future__ import annotations
@@ -42,6 +48,7 @@ from repro.api import (VARIANT_TO_ALGO, ExperimentSpec, algorithm_info,
                        build, list_algorithms)
 from repro.configs import get_config, get_smoke
 from repro.core import MomentsAccountant, calibrate_sigma, ldp_epsilon
+from repro.core.comm_round import resolve_backend
 from repro.data import batch_source
 from repro.launch.runtime import run_chunked
 from repro.models import build_model
@@ -106,11 +113,25 @@ def resolve_privacy(info, args, start: int, manifest_extra: dict):
     return sigma_p, acct, rounds_prev
 
 
+def model_config(arch: str, smoke: bool = False, n_layers=None):
+    """The config the trainer runs: published (or ``--smoke``), remat off,
+    and with ``n_layers`` the depth cut to that many layers -- the only
+    field the cut changes."""
+    cfg = get_smoke(arch) if smoke else get_config(arch)
+    cfg = dataclasses.replace(cfg, remat=False)
+    if n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    return cfg
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (CPU-trainable)")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the depth to N layers; every width stays as "
+                         "the config publishes it")
     ap.add_argument("--algo", default=None, choices=list(list_algorithms()),
                     help="registered algorithm (default porter-gc; "
                          "see repro.api)")
@@ -167,13 +188,14 @@ def main(argv=None):
         ap.error("--algo and --variant are mutually exclusive")
     if args.chunk < 1:
         ap.error("--chunk must be >= 1")
+    if args.n_layers is not None and args.n_layers < 1:
+        ap.error("--n-layers must be >= 1")
     algo_name = (args.algo or
                  (VARIANT_TO_ALGO[args.variant] if args.variant
                   else "porter-gc"))
     info = algorithm_info(algo_name)
 
-    cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    cfg = dataclasses.replace(cfg, remat=False)
+    cfg = model_config(args.arch, args.smoke, args.n_layers)
     bundle = build_model(cfg)
 
     # probe the checkpoint before calibrating: resume must keep the sigma
@@ -235,12 +257,17 @@ def main(argv=None):
     mp_note = "".join(
         [f" planes={args.plane_dtype}" if args.plane_dtype else "",
          f" remat={args.remat_policy}" if args.remat_policy else ""])
-    print(f"[model] {cfg.name}: {n_params/1e6:.2f}M params, "
+    devices = jax.devices()
+    print(f"[model] {cfg.name}: {cfg.n_layers} layers, "
+          f"{n_params/1e6:.2f}M params, "
           f"{args.agents} agents ({top_note}), "
           f"{args.compressor}(rho={args.frac}) algo={algo_name} "
-          f"chunk={args.chunk}{mp_note}")
+          f"chunk={args.chunk}{mp_note} on {devices[0].platform} "
+          f"{devices[0].device_kind} x{len(devices)} "
+          f"comm={resolve_backend(spec.comm_backend)}")
 
     state = algo.init(params)
+    del params  # the state holds its own copy; free the device buffers
     if start > 0:
         from repro.launch.checkpoint import restore_state
         state = restore_state(args.ckpt_dir, like=state)
@@ -328,4 +355,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro._env import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

@@ -72,39 +72,45 @@ def _tree_allclose(a, b, atol=1e-5):
 
 
 # ---------------------------------------------------------------------------
-# flat tile layout: padding correctness
+# per-leaf plane application: dtypes, odd shapes, structure checks
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("stacked", [True, False])
-def test_flatten_roundtrip_odd_shapes(stacked):
+def test_plane_apply_roundtrip_odd_shapes(stacked):
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, len(ODD_PARAMS))
     lead = (N,) if stacked else ()
     tree = {name: jax.random.normal(k, lead + p.shape).astype(
                 jnp.float32 if i % 2 == 0 else jnp.bfloat16)
             for i, (k, (name, p)) in enumerate(zip(ks, ODD_PARAMS.items()))}
-    spec = FL.flat_spec(tree, stacked=stacked)
-    planes = FL.to_planes(tree, spec)
-    assert planes.shape == spec.plane_shape
-    assert planes.shape[-1] == FL.TILE
-    assert planes.dtype == jnp.float32
-    # padding region is zero (kernels may compute garbage there; from_planes
-    # must never read it back)
-    if stacked:
-        flat = planes.reshape(N, -1)
-        assert float(jnp.abs(flat[:, spec.d:]).max()) == 0.0
-    back = FL.from_planes(planes, spec)
+    seen = []
+
+    def kernel(j, a, b):
+        seen.append(j)
+        return a.astype(jnp.float32) + b.astype(jnp.float32), a
+
+    twice, same = FL.plane_apply(kernel, (tree, tree), 2)
+    assert seen == list(range(len(tree)))
     for name in tree:
-        assert back[name].dtype == tree[name].dtype
-        np.testing.assert_allclose(np.asarray(back[name], np.float32),
-                                   np.asarray(tree[name], np.float32),
-                                   atol=2e-2 if tree[name].dtype ==
-                                   jnp.bfloat16 else 1e-7)
+        assert twice[name].dtype == same[name].dtype == tree[name].dtype
+        np.testing.assert_array_equal(np.asarray(same[name], np.float32),
+                                      np.asarray(tree[name], np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(twice[name], np.float32),
+            2 * np.asarray(tree[name], np.float32))
 
 
-def test_flatten_rejects_mismatched_agent_axis():
-    with pytest.raises(ValueError):
-        FL.flat_spec({"a": jnp.zeros((4, 3)), "b": jnp.zeros((5, 3))})
+def test_ef_kernels_reject_mismatched_operands():
+    a, b = jnp.zeros((4, 8)), jnp.zeros((4, 9))
+    with pytest.raises(ValueError, match="same-shape"):
+        ops.ef_gossip(a, a, a, a, b, 0.3, interpret=True)
+
+
+def test_plane_apply_rejects_mismatched_trees():
+    with pytest.raises(ValueError, match="same-structure"):
+        FL.plane_apply(lambda j, a, b: (a,),
+                       ({"a": jnp.zeros((4, 3))},
+                        {"a": jnp.zeros((4, 3)), "b": jnp.zeros((4, 3))}), 1)
 
 
 # ---------------------------------------------------------------------------
@@ -303,21 +309,24 @@ def test_engine_rejects_unknown_backend():
 # per-shard planes: model-sharded mesh parity + collective inspection
 # ---------------------------------------------------------------------------
 
-def test_specs_have_model_axes():
-    from jax.sharding import PartitionSpec as P
-    agent_only = {"a": P("data", None), "b": P(("pod", "data"), None)}
-    assert not FL.specs_have_model_axes(agent_only, ("pod", "data"))
-    sharded = {"a": P("data", None, "model"), "b": P("data", None)}
-    assert FL.specs_have_model_axes(sharded, ("data",))
-    # a non-agent axis folded into a tuple entry still counts
-    assert FL.specs_have_model_axes({"a": P(("data", "model"))}, ("data",))
-
-
 def test_engine_without_mesh_keeps_single_plane_path():
     comp = make_compressor("top_k", frac=0.1)
     eng = CommRound(compressor=comp, mixer=make_mixer(_top(), "dense"),
                     backend="pallas", interpret=True)
-    assert eng._sharded_planes() is None
+    assert eng._plane_mesh() is None
+
+
+def test_engine_on_a_mesh_uses_per_shard_planes():
+    # agent-only specs too: a Mosaic call outside shard_map would run on
+    # gathered buffers
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
+    comp = make_compressor("top_k", frac=0.1)
+    mesh = make_mesh((1,), ("data",))
+    eng = CommRound(compressor=comp, mixer=make_mixer(_top(), "dense"),
+                    backend="pallas", interpret=True, mesh=mesh,
+                    leaf_specs={"w": P("data", None)})
+    assert eng._plane_mesh() is not None
 
 
 _SHARDED_SCRIPT = textwrap.dedent("""
@@ -325,10 +334,11 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.api import ExperimentSpec, build_engine, resolve_compressor
     from repro.launch.steps import make_shard_local_compress
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     n = 4
     key = jax.random.PRNGKey(0)
 
@@ -363,7 +373,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
         pal = build_engine(base.replace(gossip_mode=gossip_mode,
                                         comm_backend="pallas",
                                         interpret=True), **kw)
-        assert pal._sharded_planes() is not None, "per-shard planes inactive"
+        assert pal._plane_mesh() is not None, "per-shard planes inactive"
         return ref, pal
 
     def check(tref, tpal, atol=1e-5, rtol=1e-5):

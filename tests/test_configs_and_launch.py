@@ -170,3 +170,43 @@ def test_decode_window_rules():
                             SH.SHAPES["long_500k"]) == 4096
     assert SH.decode_window(get_config("zamba2-7b"),
                             SH.SHAPES["decode_32k"]) == "cfg"
+
+
+# ---------------------------------------------------------------------------
+# train.py: the depth cut and the device line
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch", ["minicpm3-4b", "tinyllama-1.1b"])
+def test_train_n_layers_keeps_published_widths(arch):
+    import dataclasses
+    from repro.launch.train import model_config
+    full = model_config(arch)
+    cut = model_config(arch, n_layers=1)
+    assert cut.n_layers == 1
+    assert dataclasses.replace(cut, n_layers=full.n_layers) == full
+    assert full == dataclasses.replace(get_config(arch), remat=False)
+
+
+def test_train_smoke_runs_unchanged_by_depth_option(tmp_path, capsys):
+    import json
+    from repro.launch.train import main, model_config
+    assert model_config("tinyllama-1.1b", smoke=True) == \
+        model_config("tinyllama-1.1b", smoke=True,
+                     n_layers=get_smoke("tinyllama-1.1b").n_layers)
+    base = ["--arch", "tinyllama-1.1b", "--smoke", "--steps", "2",
+            "--batch", "1", "--seq", "16", "--agents", "2", "--log-every",
+            "1"]
+    smoke_layers = str(get_smoke("tinyllama-1.1b").n_layers)
+    hist, codes = [], []
+    for extra in ([], ["--n-layers", smoke_layers]):
+        out = tmp_path / f"h{len(hist)}.json"
+        codes.append(main(base + extra + ["--out", str(out)]))
+        hist.append([h["loss"] for h in json.loads(out.read_text())])
+    assert codes[0] == codes[1]
+    assert len(hist[0]) == 2 and np.isfinite(hist[0]).all()
+    assert hist[0] == hist[1]
+    line = [l for l in capsys.readouterr().out.splitlines()
+            if l.startswith("[model]")][0]
+    dev = jax.devices()[0]
+    assert f"on {dev.platform} {dev.device_kind} x{len(jax.devices())}" \
+        in line

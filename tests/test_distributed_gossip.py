@@ -14,11 +14,12 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.core import make_topology, make_compressor
     from repro.core.gossip import (make_dense_mixer, make_ring_mixer,
                                    make_packed_mixer)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     top = make_topology("ring", 4, weights="metropolis")
     key = jax.random.PRNGKey(0)
     # agent-stacked tree, second leaf model-sharded on its last dim
@@ -42,8 +43,7 @@ SCRIPT = textwrap.dedent("""
     # compress per (agent row x model shard) = per shard-local block
     comp = make_compressor("block_top_k", frac=0.25, block=4)
     def shard_local(t):
-        from repro.compat import shard_map
-        f = shard_map(lambda tt: jax.tree_util.tree_map(
+        f = jax.shard_map(lambda tt: jax.tree_util.tree_map(
             lambda l: comp(None, l), tt), mesh=mesh, in_specs=(specs,),
             out_specs=specs, check_vma=False)
         return f(t)
@@ -61,7 +61,7 @@ SCRIPT = textwrap.dedent("""
 
     # n=2 ring: both ppermute shifts deliver the same agent; the executor
     # must apply the neighbor once (regression: w_self*x + 2*w01*neighbor)
-    mesh2 = jax.make_mesh((2,), ("data",))
+    mesh2 = make_mesh((2,), ("data",))
     top2 = make_topology("ring", 2, weights="metropolis")
     tree2 = {"a": jax.random.normal(key, (2, 5, 3)),
              "b": jax.random.normal(key, (2, 7))}
@@ -78,7 +78,7 @@ SCRIPT = textwrap.dedent("""
     print("ring2-ok")
 
     # multi-pod ring seam: agent grid ('pod','data') on a (2,2,2) mesh
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     top4 = make_topology("ring", 4, weights="metropolis")
     specs3 = {"a": P(("pod", "data"), None, "model"),
               "b": P(("pod", "data"), None)}

@@ -420,6 +420,7 @@ SHARD_SCRIPT = textwrap.dedent("""
     import collections
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.api import ExperimentSpec, build
     from repro.analysis.hlo import collective_counts
 
@@ -436,7 +437,7 @@ SHARD_SCRIPT = textwrap.dedent("""
     l = (f @ w_true > 0).astype(np.float32)
     params0 = {"w": jnp.zeros(D), "b": jnp.zeros(())}
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     def shardings(tree):
         def spec(leaf):
             if getattr(leaf, "ndim", 0) >= 1 and leaf.shape[0] == N:

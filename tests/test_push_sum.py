@@ -323,11 +323,12 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.api import ExperimentSpec, build, build_engine
     from repro.core import mixing as MX
 
     n, d = 8, 24
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     specs = {"w": P("data", None)}
     sh = NamedSharding(mesh, specs["w"])
     rng = np.random.default_rng(0)

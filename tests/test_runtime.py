@@ -292,3 +292,18 @@ def test_resolve_privacy_fresh_vs_resume():
     # accounted for -- refuse rather than re-calibrate over spent rounds
     with pytest.raises(ValueError, match="no sigma_p"):
         resolve_privacy(info, args, 10, {})
+
+
+def test_dealias_copies_arrays_that_share_a_buffer():
+    # two array objects over one buffer (device_put to the sharding it
+    # already has) must not both be donated
+    from repro.launch.runtime import _dealias
+    a = jnp.arange(8.0)
+    b = jax.device_put(a, a.sharding)
+    assert b is not a
+    out = _dealias({"a": a, "b": b, "c": a})
+    ptrs = [out[k].addressable_shards[0].data.unsafe_buffer_pointer()
+            for k in "abc"]
+    assert len(set(ptrs)) == 3
+    for k in "abc":
+        np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(a))

@@ -120,9 +120,10 @@ def test_leaf_cast_properties(seed):
 
 
 def test_kernel_level_parity_padded_plane():
-    """The raw (tiles, TILE) kernel matches its reference on shared bits."""
+    """The raw (tiles, 8 * 1024) kernel matches its reference on shared
+    bits."""
     key = jax.random.PRNGKey(3)
-    x = _uniform(key, (3, SRK.TILE), 1.0)
+    x = _uniform(key, (3, 8 * 1024), 1.0)
     bits = jax.random.bits(jax.random.fold_in(key, 1), x.shape, jnp.uint32)
     a = SRK.sr_cast(x, bits, interpret=True)
     b = SRK.sr_cast_ref(x, bits)

@@ -192,13 +192,13 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.api import ExperimentSpec, build, build_engine
-    from repro.compat import shard_map
     from repro.core import wire_formats as WF
     from repro.core.gossip import make_dense_mixer
     from repro.core.mixing import make_topology
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     key = jax.random.PRNGKey(0)
     tree = {"a": jax.random.normal(key, (4, 6, 8)),
             "b": jax.random.normal(key, (4, 10))}
@@ -220,8 +220,8 @@ SCRIPT = textwrap.dedent("""
                         v.shape[0], v.shape)
                 return jax.vmap(one)(flat).reshape(l.shape)
             return jax.tree_util.tree_map(leaf, tt)
-        f = shard_map(per_shard, mesh=mesh, in_specs=(specs,),
-                      out_specs=specs, check_vma=False)
+        f = jax.shard_map(per_shard, mesh=mesh, in_specs=(specs,),
+                          out_specs=specs, check_vma=False)
         return jax.jit(f)(tree)
 
     codec = WF.make_wire_format("block_top_k", frac=0.25)
@@ -269,7 +269,7 @@ SCRIPT = textwrap.dedent("""
 
     # n=2 ring folds both bands onto the one live neighbor -- the codec
     # executor must apply the neighbor's unpacked buffers exactly once
-    mesh2 = jax.make_mesh((2,), ("data",))
+    mesh2 = make_mesh((2,), ("data",))
     top2 = make_topology("ring", 2, weights="metropolis")
     specs2 = {"a": P("data", None, None), "b": P("data", None)}
     sh2 = {k: NamedSharding(mesh2, specs2[k]) for k in specs2}
