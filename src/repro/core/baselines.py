@@ -39,7 +39,7 @@ from . import clipping
 from .comm_round import CommRound, resolve_engine
 from .compression import Compressor
 from .gossip import MixFn, apply_mixer, gossip_wire_bytes
-from .porter import LossFn, average_params, consensus_error
+from .porter import LossFn, average_params, consensus_error, perturb
 
 __all__ = [
     "DsgdState", "dsgd_init", "dsgd_step",
@@ -65,13 +65,7 @@ def _param_count(tree, n_agents: int) -> int:
 def _dp_gradient(loss_fn, params, batch, key, tau, clip_mode, sigma_p):
     g, loss = clipping.clipped_grad_accumulate(loss_fn, params, batch, tau,
                                                clip_mode)
-    leaves, treedef = jax.tree_util.tree_flatten(g)
-    keys = jax.random.split(key, len(leaves))
-    g = treedef.unflatten([
-        l + sigma_p * jax.random.normal(k, l.shape, l.dtype)
-        for k, l in zip(keys, leaves)
-    ])
-    return loss, g
+    return loss, perturb(g, key, sigma_p)
 
 
 # ---------------------------------------------------------------------------
@@ -105,7 +99,8 @@ def dsgd_step(eta: float, gamma: float, loss_fn: LossFn, mixer: MixFn,
             g = clipping.tree_clip(g, tau, clip_mode)
         return loss, g
 
-    losses, g = jax.vmap(agent_grad)(state.x, batch, keys)
+    with jax.named_scope("oracle"):
+        losses, g = jax.vmap(agent_grad)(state.x, batch, keys)
     # W_t X; the step counter selects the round's matrix under a schedule
     mixed = apply_mixer(mixer, state.x, state.step)
     x = _tree(lambda x0, wx, gg: x0 + gamma * (wx - x0) - eta * gg,
@@ -115,9 +110,11 @@ def dsgd_step(eta: float, gamma: float, loss_fn: LossFn, mixer: MixFn,
     wire = gossip_wire_bytes(getattr(mixer, "wire_mode", "dense"), n,
                              _param_count(state.x, n),
                              frac=1.0 if frac is None else frac)
-    return DsgdState(x=x, step=state.step + 1), {
-        "loss": jnp.mean(losses), "consensus_x": consensus_error(x),
-        "wire_bytes": jnp.asarray(wire, jnp.float32)}
+    with jax.named_scope("step.metrics"):
+        metrics = {"loss": jnp.mean(losses),
+                   "consensus_x": consensus_error(x),
+                   "wire_bytes": jnp.asarray(wire, jnp.float32)}
+    return DsgdState(x=x, step=state.step + 1), metrics
 
 
 # ---------------------------------------------------------------------------
@@ -159,13 +156,17 @@ def choco_step(eta: float, gamma: float, loss_fn: LossFn,
             g = clipping.tree_clip(g, tau, clip_mode)
         return loss, g
 
-    losses, g = jax.vmap(agent_grad)(state.x, batch, keys)
+    with jax.named_scope("oracle"):
+        losses, g = jax.vmap(agent_grad)(state.x, batch, keys)
     x_half = _tree(lambda x0, gg: x0 - eta * gg, state.x, g)
     x, q, m = eng.gossip_apply(k_c, x_half, state.q, state.m, gamma,
                                t=state.step)
-    return ChocoState(x=x, q=q, m=m, step=state.step + 1), {
-        "loss": jnp.mean(losses), "consensus_x": consensus_error(x),
-        "wire_bytes": jnp.asarray(eng.wire_bytes(state.x), jnp.float32)}
+    with jax.named_scope("step.metrics"):
+        metrics = {"loss": jnp.mean(losses),
+                   "consensus_x": consensus_error(x),
+                   "wire_bytes": jnp.asarray(eng.wire_bytes(state.x),
+                                             jnp.float32)}
+    return ChocoState(x=x, q=q, m=m, step=state.step + 1), metrics
 
 
 # ---------------------------------------------------------------------------
@@ -187,8 +188,9 @@ def dpsgd_init(params) -> DpSgdState:
 def dpsgd_step(eta: float, loss_fn: LossFn, state: DpSgdState, batch, key,
                tau: float = 1.0, clip_mode: str = "smooth",
                sigma_p: float = 0.0) -> Tuple[DpSgdState, Dict[str, jax.Array]]:
-    loss, g = _dp_gradient(loss_fn, state.x, batch, key, tau, clip_mode,
-                           sigma_p)
+    with jax.named_scope("oracle"):
+        loss, g = _dp_gradient(loss_fn, state.x, batch, key, tau, clip_mode,
+                               sigma_p)
     x = _tree(lambda x0, gg: x0 - eta * gg, state.x, g)
     # one dense gradient upload to the server per round, at each buffer's
     # actual dtype width (a bf16 run moves half the bytes of an f32 one)
@@ -244,7 +246,8 @@ def soteria_step(eta: float, alpha_shift: float, loss_fn: LossFn,
         loss, g = _dp_gradient(loss_fn, state.x, b, k, tau, clip_mode, sigma_p)
         return loss, g
 
-    losses, g = jax.vmap(client)(state.h, batch, keys)
+    with jax.named_scope("oracle"):
+        losses, g = jax.vmap(client)(state.h, batch, keys)
     c, h = eng.shift(k_c, g, state.h, scale=alpha_shift)
     c_bar = _tree(lambda cc: jnp.mean(cc, axis=0), c)
     g_tilde = _tree(jnp.add, state.h_bar, c_bar)
@@ -254,6 +257,7 @@ def soteria_step(eta: float, alpha_shift: float, loss_fn: LossFn,
     # matching the LDP literature's upload accounting); accounted from the
     # engine so the metric always reflects the compressor that actually ran
     wire = eng.wire_bytes(state.h)
-    return SoteriaState(x=x, h=h, h_bar=h_bar, step=state.step + 1), {
-        "loss": jnp.mean(losses),
-        "wire_bytes": jnp.asarray(wire, jnp.float32)}
+    with jax.named_scope("step.metrics"):
+        metrics = {"loss": jnp.mean(losses),
+                   "wire_bytes": jnp.asarray(wire, jnp.float32)}
+    return SoteriaState(x=x, h=h, h_bar=h_bar, step=state.step + 1), metrics

@@ -70,13 +70,14 @@ def clip21_update(g_est: Any, g_raw: Any, tau: float) -> Any:
     the new residual r' = g_raw - g_est' satisfies both
     ``||r'|| <= ||r||`` and ``||r'|| <= max(||r|| - tau, 0)``.
     """
-    delta = jax.tree_util.tree_map(lambda a, b: a - b, g_raw, g_est)
-    factor = clipping.clip_factor(clipping.tree_global_norm(delta), tau,
-                                  "piecewise")
-    return jax.tree_util.tree_map(
-        lambda ge, gr, d: jnp.where(factor >= 1.0, gr,
-                                    (ge + factor * d).astype(gr.dtype)),
-        g_est, g_raw, delta)
+    with jax.named_scope("oracle.clip"):
+        delta = jax.tree_util.tree_map(lambda a, b: a - b, g_raw, g_est)
+        factor = clipping.clip_factor(clipping.tree_global_norm(delta), tau,
+                                      "piecewise")
+        return jax.tree_util.tree_map(
+            lambda ge, gr, d: jnp.where(factor >= 1.0, gr,
+                                        (ge + factor * d).astype(gr.dtype)),
+            g_est, g_raw, delta)
 
 
 def clip21_init(params: Any, n_agents: int, w=None,
@@ -114,15 +115,16 @@ def clip21_step(
     agent_keys = jax.random.split(k_noise, n)
     raw_cfg = dataclasses.replace(cfg, variant="beer")
     grad_fn = functools.partial(_agent_gradient, raw_cfg, loss_fn)
-    losses, g_raw = jax.vmap(grad_fn)(state.base.x, batch, agent_keys)
-
-    g_est = jax.vmap(lambda ge, gr: clip21_update(ge, gr, cfg.tau))(
-        state.g_est, g_raw)
+    with jax.named_scope("oracle"):
+        losses, g_raw = jax.vmap(grad_fn)(state.base.x, batch, agent_keys)
+        g_est = jax.vmap(lambda ge, gr: clip21_update(ge, gr, cfg.tau))(
+            state.g_est, g_raw)
 
     base, metrics = porter_step(cfg, loss_fn, mixer, compressor, state.base, batch,
                                 key, compress_fn=compress_fn, engine=engine,
                                 grad_override=(losses, g_est))
-    resid = jax.tree_util.tree_map(lambda a, b: a - b, g_raw, g_est)
-    metrics["clip_residual"] = (clipping.tree_global_norm(resid)
-                                / jnp.sqrt(jnp.float32(n)))
+    with jax.named_scope("step.metrics"):
+        resid = jax.tree_util.tree_map(lambda a, b: a - b, g_raw, g_est)
+        metrics["clip_residual"] = (clipping.tree_global_norm(resid)
+                                    / jnp.sqrt(jnp.float32(n)))
     return Clip21State(base=base, g_est=g_est), metrics

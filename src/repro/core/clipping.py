@@ -65,9 +65,11 @@ def clip_factor(norm: jax.Array, tau: float, mode: ClipMode) -> jax.Array:
 
 def tree_clip(tree, tau: float, mode: ClipMode = "smooth"):
     """Clip a pytree by its global l2 norm."""
-    norm = tree_global_norm(tree)
-    c = clip_factor(norm, tau, mode)
-    return jax.tree_util.tree_map(lambda l: (l * c).astype(l.dtype), tree)
+    with jax.named_scope("oracle.clip"):
+        norm = tree_global_norm(tree)
+        c = clip_factor(norm, tau, mode)
+        return jax.tree_util.tree_map(lambda l: (l * c).astype(l.dtype),
+                                      tree)
 
 
 def clipped_grad_accumulate(
@@ -95,11 +97,13 @@ def clipped_grad_accumulate(
             lambda x: jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=0), batch)
         loss, g = grad_fn(params, sample)
         g = tree_clip(g, tau, mode)
-        acc = jax.tree_util.tree_map(jnp.add, acc, g)
+        with jax.named_scope("oracle.clip"):
+            acc = jax.tree_util.tree_map(jnp.add, acc, g)
         return (acc, loss_acc + loss), None
 
     zeros = jax.tree_util.tree_map(lambda p: jnp.zeros_like(p, dtype=jnp.float32),
                                    params)
     (acc, loss_sum), _ = jax.lax.scan(body, (zeros, 0.0), jnp.arange(b))
-    mean_g = jax.tree_util.tree_map(lambda a: a / b, acc)
+    with jax.named_scope("oracle.clip"):
+        mean_g = jax.tree_util.tree_map(lambda a: a / b, acc)
     return mean_g, loss_sum / b

@@ -274,7 +274,10 @@ class CommRound:
         """
         if not any(_sr_dtype(t) for t in trees):
             return key, None
-        k_c, k_sr = jax.random.split(key)
+        # the SR writeback's key: a part of the EF update it feeds
+        with jax.named_scope("engine.ef_update"), \
+                jax.named_scope("engine.sr_bits"):
+            k_c, k_sr = jax.random.split(key)
         return k_c, k_sr
 
     def _plane_update(self, kfn, trees, sr_key):
@@ -298,10 +301,11 @@ class CommRound:
 
         def kernel(leaf, *leaves):
             outs = kfn(*leaves, out_dtype=jnp.float32)
-            key = jax.random.fold_in(sr_key, leaf)
-            for ax in axis_names:
-                key = jax.random.fold_in(key, jax.lax.axis_index(ax))
-            keys = jax.random.split(key, 3)
+            with jax.named_scope("engine.sr_bits"):
+                key = jax.random.fold_in(sr_key, leaf)
+                for ax in axis_names:
+                    key = jax.random.fold_in(key, jax.lax.axis_index(ax))
+                keys = tuple(jax.random.split(key, 3))
             return tuple(ops.sr_cast(o, keys[i], **kw) if needs[i] else o
                          for i, o in enumerate(outs))
 
@@ -331,9 +335,16 @@ class CommRound:
 
     def compress(self, key: jax.Array, delta):
         """c = C(delta), in the pytree domain (shard-local aware)."""
-        if self.compress_fn is not None:
-            return self.compress_fn(key, delta)
-        return compress_stacked(self.compressor, key, delta)
+        with jax.named_scope("engine.compress"):
+            if self.compress_fn is not None:
+                return self.compress_fn(key, delta)
+            return compress_stacked(self.compressor, key, delta)
+
+    @staticmethod
+    def _increment(y, q):
+        """``y - q`` in the surrogate's dtype (see :meth:`exchange`)."""
+        with jax.named_scope("engine.compress"):
+            return _tree(lambda a, b: (a - b).astype(b.dtype), y, q)
 
     def exchange(self, key: jax.Array, y, q, t=None) -> Tuple[Any, Any]:
         """Compress the increment of ``y`` against surrogate ``q`` and mix.
@@ -357,9 +368,11 @@ class CommRound:
         rounding is reserved for the *accumulating* q/m/v writebacks where
         bias compounds.
         """
-        delta = _tree(lambda a, b: (a - b).astype(b.dtype), y, q)
+        delta = self._increment(y, q)
         if getattr(self.mixer, "wire_codec", None) is not None:
-            return self.mixer.exchange(key, delta, t)
+            # the codec executor packs (``engine.compress``) inside its mix
+            with jax.named_scope("engine.mix"):
+                return self.mixer.exchange(key, delta, t)
         c = self.compress(key, delta)
         return c, apply_mixer(self.mixer, c, t)
 
@@ -376,10 +389,11 @@ class CommRound:
         bitcast bytes on the codec buffers), so the collective count is
         identical to :meth:`exchange` -- the HLO tests pin this.
         """
-        delta = _tree(lambda a, b: (a - b).astype(b.dtype), y, q)
+        delta = self._increment(y, q)
         dw = jnp.subtract(yw, qw)
         if getattr(self.mixer, "wire_codec", None) is not None:
-            return self.mixer.exchange_ps(key, delta, dw, t)
+            with jax.named_scope("engine.mix"):
+                return self.mixer.exchange_ps(key, delta, dw, t)
         push = getattr(self.mixer, "push", None)
         if push is None:
             raise ValueError(
@@ -390,7 +404,8 @@ class CommRound:
                 "weight scalar -- use gossip='ring'/'dense' or a bit-packed "
                 "wire format for directed (column-stochastic) topologies")
         c = self.compress(key, delta)
-        wc, wcw = push(c, dw, t)
+        with jax.named_scope("engine.mix"):
+            wc, wcw = push(c, dw, t)
         return c, wc, dw, wcw
 
     # -- fused state updates ------------------------------------------------
@@ -416,28 +431,29 @@ class CommRound:
         ``sr_key``: stochastic-rounding key for bf16 buffers (from
         :meth:`sr_split`); None falls back to deterministic casts.
         """
-        kw = self._kernel_kw()
-        if self._use_pallas():
-            qo, mo, vo = self._plane_update(
-                lambda *p, out_dtype=None: ops.ef_track(
-                    *p, gamma, out_dtype=out_dtype, **kw),
-                (q, m, v, c, wc, g, g_prev), sr_key)
-            return vo, qo, mo
-        if sr_key is not None and any(_sr_dtype(t) for t in (q, m, v)):
-            q2f = _tree(jnp.add, self._f32(q), self._f32(c))
-            m2f = _tree(jnp.add, self._f32(m), self._f32(wc))
-            v2f = _tree(lambda v0, mm, qq, gn, gp: v0 + gamma * (mm - qq)
-                        + gn - gp, self._f32(v), m2f, q2f, self._f32(g),
-                        self._f32(g_prev))
-            kq, km, kv = jax.random.split(sr_key, 3)
-            return (self._sr_writeback(v2f, v, kv),
-                    self._sr_writeback(q2f, q, kq),
-                    self._sr_writeback(m2f, m, km))
-        q2 = _tree(jnp.add, q, c)
-        m2 = _tree(jnp.add, m, wc)
-        v2 = _tree(lambda v0, mm, qq, gn, gp: v0 + gamma * (mm - qq)
-                   + gn - gp, v, m2, q2, g, g_prev)
-        return v2, q2, m2
+        with jax.named_scope("engine.ef_update"):
+            kw = self._kernel_kw()
+            if self._use_pallas():
+                qo, mo, vo = self._plane_update(
+                    lambda *p, out_dtype=None: ops.ef_track(
+                        *p, gamma, out_dtype=out_dtype, **kw),
+                    (q, m, v, c, wc, g, g_prev), sr_key)
+                return vo, qo, mo
+            if sr_key is not None and any(_sr_dtype(t) for t in (q, m, v)):
+                q2f = _tree(jnp.add, self._f32(q), self._f32(c))
+                m2f = _tree(jnp.add, self._f32(m), self._f32(wc))
+                v2f = _tree(lambda v0, mm, qq, gn, gp: v0 + gamma * (mm - qq)
+                            + gn - gp, self._f32(v), m2f, q2f, self._f32(g),
+                            self._f32(g_prev))
+                kq, km, kv = jax.random.split(sr_key, 3)
+                return (self._sr_writeback(v2f, v, kv),
+                        self._sr_writeback(q2f, q, kq),
+                        self._sr_writeback(m2f, m, km))
+            q2 = _tree(jnp.add, q, c)
+            m2 = _tree(jnp.add, m, wc)
+            v2 = _tree(lambda v0, mm, qq, gn, gp: v0 + gamma * (mm - qq)
+                       + gn - gp, v, m2, q2, g, g_prev)
+            return v2, q2, m2
 
     def step(self, key, x, q, m, v, gamma: float, eta: float, t=None):
         """PORTER Algorithm 1 lines 13-14 (parameter step).
@@ -459,29 +475,30 @@ class CommRound:
         params ``x`` normally stay f32 and take an exact writeback; only
         the q/m surrogates round stochastically).
         """
-        kw = self._kernel_kw()
-        if self._use_pallas():
-            qo, mo, xo = self._plane_update(
-                lambda *p, out_dtype=None: ops.ef_step(
-                    *p, gamma, eta, out_dtype=out_dtype, **kw),
-                (q, m, x, c, wc, v), sr_key)
-            return xo, qo, mo
-        if sr_key is not None and any(_sr_dtype(t) for t in (q, m, x)):
-            q2f = _tree(jnp.add, self._f32(q), self._f32(c))
-            m2f = _tree(jnp.add, self._f32(m), self._f32(wc))
-            x2f = _tree(lambda x0, mm, qq, vv:
-                        x0 + gamma * (mm - qq) - eta * vv,
-                        self._f32(x), m2f, q2f, self._f32(v))
-            kq, km, kx = jax.random.split(sr_key, 3)
-            return (self._sr_writeback(x2f, x, kx),
-                    self._sr_writeback(q2f, q, kq),
-                    self._sr_writeback(m2f, m, km))
-        q2 = _tree(jnp.add, q, c)
-        m2 = _tree(jnp.add, m, wc)
-        x2 = _tree(lambda x0, mm, qq, vv:
-                   (x0 + gamma * (mm - qq) - eta * vv).astype(x0.dtype),
-                   x, m2, q2, v)
-        return x2, q2, m2
+        with jax.named_scope("engine.ef_update"):
+            kw = self._kernel_kw()
+            if self._use_pallas():
+                qo, mo, xo = self._plane_update(
+                    lambda *p, out_dtype=None: ops.ef_step(
+                        *p, gamma, eta, out_dtype=out_dtype, **kw),
+                    (q, m, x, c, wc, v), sr_key)
+                return xo, qo, mo
+            if sr_key is not None and any(_sr_dtype(t) for t in (q, m, x)):
+                q2f = _tree(jnp.add, self._f32(q), self._f32(c))
+                m2f = _tree(jnp.add, self._f32(m), self._f32(wc))
+                x2f = _tree(lambda x0, mm, qq, vv:
+                            x0 + gamma * (mm - qq) - eta * vv,
+                            self._f32(x), m2f, q2f, self._f32(v))
+                kq, km, kx = jax.random.split(sr_key, 3)
+                return (self._sr_writeback(x2f, x, kx),
+                        self._sr_writeback(q2f, q, kq),
+                        self._sr_writeback(m2f, m, km))
+            q2 = _tree(jnp.add, q, c)
+            m2 = _tree(jnp.add, m, wc)
+            x2 = _tree(lambda x0, mm, qq, vv:
+                       (x0 + gamma * (mm - qq) - eta * vv).astype(x0.dtype),
+                       x, m2, q2, v)
+            return x2, q2, m2
 
     def step_ps(self, key, x, q, m, v, xw, qw, mw, gamma: float, eta: float,
                 t=None):
@@ -511,10 +528,11 @@ class CommRound:
         """
         x2, q2, m2 = self.step_update(c, wc, x, q, m, v, gamma, eta,
                                       sr_key=sr_key)
-        qw2 = qw + cw
-        mw2 = mw + wcw
-        xw2 = (xw + gamma * (mw2 - qw2)).astype(xw.dtype)
-        return x2, q2, m2, xw2, qw2, mw2
+        with jax.named_scope("engine.ef_update"):
+            qw2 = qw + cw
+            mw2 = mw + wcw
+            xw2 = (xw + gamma * (mw2 - qw2)).astype(xw.dtype)
+            return x2, q2, m2, xw2, qw2, mw2
 
     def gossip_apply(self, key, y, q, m, gamma: float, scale: float = 1.0,
                      t=None):
@@ -527,28 +545,29 @@ class CommRound:
         """
         key, sr_key = self.sr_split(key, (q, m, y))
         c, wc = self.exchange(key, y, q, t)
-        kw = self._kernel_kw()
-        if self._use_pallas():
-            qo, mo, yo = self._plane_update(
-                lambda *p, out_dtype=None: ops.ef_gossip(
-                    *p, gamma, scale, out_dtype=out_dtype, **kw),
-                (q, m, y, c, wc), sr_key)
-            return yo, qo, mo
-        if sr_key is not None and any(_sr_dtype(t) for t in (q, m, y)):
-            q2f = _tree(lambda a, b: a + scale * b, self._f32(q),
-                        self._f32(c))
-            m2f = _tree(lambda a, b: a + scale * b, self._f32(m),
-                        self._f32(wc))
-            y2f = _tree(lambda y0, mm, qq: y0 + gamma * (mm - qq),
-                        self._f32(y), m2f, q2f)
-            kq, km, ky = jax.random.split(sr_key, 3)
-            return (self._sr_writeback(y2f, y, ky),
-                    self._sr_writeback(q2f, q, kq),
-                    self._sr_writeback(m2f, m, km))
-        q2 = _tree(lambda a, b: a + scale * b, q, c)
-        m2 = _tree(lambda a, b: a + scale * b, m, wc)
-        y2 = _tree(lambda y0, mm, qq: y0 + gamma * (mm - qq), y, m2, q2)
-        return y2, q2, m2
+        with jax.named_scope("engine.ef_update"):
+            kw = self._kernel_kw()
+            if self._use_pallas():
+                qo, mo, yo = self._plane_update(
+                    lambda *p, out_dtype=None: ops.ef_gossip(
+                        *p, gamma, scale, out_dtype=out_dtype, **kw),
+                    (q, m, y, c, wc), sr_key)
+                return yo, qo, mo
+            if sr_key is not None and any(_sr_dtype(t) for t in (q, m, y)):
+                q2f = _tree(lambda a, b: a + scale * b, self._f32(q),
+                            self._f32(c))
+                m2f = _tree(lambda a, b: a + scale * b, self._f32(m),
+                            self._f32(wc))
+                y2f = _tree(lambda y0, mm, qq: y0 + gamma * (mm - qq),
+                            self._f32(y), m2f, q2f)
+                kq, km, ky = jax.random.split(sr_key, 3)
+                return (self._sr_writeback(y2f, y, ky),
+                        self._sr_writeback(q2f, q, kq),
+                        self._sr_writeback(m2f, m, km))
+            q2 = _tree(lambda a, b: a + scale * b, q, c)
+            m2 = _tree(lambda a, b: a + scale * b, m, wc)
+            y2 = _tree(lambda y0, mm, qq: y0 + gamma * (mm - qq), y, m2, q2)
+            return y2, q2, m2
 
     def shift(self, key, y, q, scale: float = 1.0):
         """SoteriaFL shifted compression (mirrorless surrogate accumulate).
@@ -556,9 +575,10 @@ class CommRound:
         c = C(y - q); q' = q + scale*c.  Returns (c, q') -- the caller owns
         the server-side aggregation of ``c`` (a mean, not a gossip mix).
         """
-        c = self.compress(key, _tree(lambda a, b: (a - b).astype(b.dtype),
-                                     y, q))
-        return c, _tree(lambda a, b: (a + scale * b).astype(a.dtype), q, c)
+        c = self.compress(key, self._increment(y, q))
+        with jax.named_scope("engine.ef_update"):
+            return c, _tree(lambda a, b: (a + scale * b).astype(a.dtype),
+                            q, c)
 
     # -- wire accounting ----------------------------------------------------
 

@@ -132,13 +132,13 @@ def apply_mixer(mixer: MixFn, tree, t=None):
     Static mixers (and ad-hoc test doubles) keep their 1-argument call
     shape; mixers built from a schedule are tagged ``time_varying`` and
     require ``t`` (the algorithm steps pass their state's step counter)."""
-    if getattr(mixer, "time_varying", False):
-        if t is None:
-            raise ValueError(
-                "this mixer runs a time-varying topology schedule and needs "
-                "the absolute round index (pass t=state.step)")
-        return mixer(tree, t)
-    return mixer(tree)
+    time_varying = getattr(mixer, "time_varying", False)
+    if time_varying and t is None:
+        raise ValueError(
+            "this mixer runs a time-varying topology schedule and needs "
+            "the absolute round index (pass t=state.step)")
+    with jax.named_scope("engine.mix"):
+        return mixer(tree, t) if time_varying else mixer(tree)
 
 
 def _schedule_table(w) -> Tuple[np.ndarray, bool]:
@@ -581,10 +581,11 @@ def _agent_index(mesh: Mesh, axes: Tuple[str, ...]):
 
 def _pack_local(codec: WF.WireFormat, key, x):
     """Pack one (1, ...) local block: returns (bufs, c_rows, d)."""
-    flat = x.reshape(-1).astype(jnp.float32)
-    rows = WF.to_windows(flat)
-    bufs = codec.pack(key, rows)
-    return bufs, codec.unpack(*bufs), flat.shape[0]
+    with jax.named_scope("engine.compress"):
+        flat = x.reshape(-1).astype(jnp.float32)
+        rows = WF.to_windows(flat)
+        bufs = codec.pack(key, rows)
+        return bufs, codec.unpack(*bufs), flat.shape[0]
 
 
 # Wire armor: float wire buffers are bitcast to same-width uints for the

@@ -129,6 +129,17 @@ def porter_init(params: Any, n_agents: int, w: Optional[np.ndarray] = None,
                        m_x=m_x, m_v=zeros, step=jnp.zeros((), jnp.int32))
 
 
+def perturb(g, key: jax.Array, sigma_p: float):
+    """The Gaussian perturbation of a DP gradient: each leaf plus
+    ``sigma_p`` times its own standard normal draw."""
+    with jax.named_scope("oracle.noise"):
+        leaves, treedef = jax.tree_util.tree_flatten(g)
+        keys = jax.random.split(key, len(leaves))
+        return treedef.unflatten([
+            l + sigma_p * jax.random.normal(k, l.shape, l.dtype)
+            for k, l in zip(keys, leaves)])
+
+
 def _agent_gradient(cfg: PorterConfig, loss_fn: LossFn, params, batch,
                     key: jax.Array) -> Tuple[jax.Array, Any]:
     """One agent's G_p (Algorithm 1 lines 5-10).  batch leaves: (b, ...)."""
@@ -136,13 +147,7 @@ def _agent_gradient(cfg: PorterConfig, loss_fn: LossFn, params, batch,
         # Option I: clip each sample's gradient, average, perturb.
         g, loss = clipping.clipped_grad_accumulate(
             loss_fn, params, batch, cfg.tau, cfg.clip_mode)
-        leaves, treedef = jax.tree_util.tree_flatten(g)
-        keys = jax.random.split(key, len(leaves))
-        noised = [
-            l + cfg.sigma_p * jax.random.normal(k, l.shape, l.dtype)
-            for k, l in zip(keys, leaves)
-        ]
-        return loss, treedef.unflatten(noised)
+        return loss, perturb(g, key, cfg.sigma_p)
     # Option II / BEER: one batch gradient, clip after (or not at all).
     loss, g = jax.value_and_grad(loss_fn)(params, batch)
     if cfg.variant == "gc":
@@ -191,13 +196,14 @@ def porter_step(
     _, k_noise, k_cv, k_cx = jax.random.split(key, 4)
 
     # ---- stochastic gradients (local; lines 4-10) -------------------------
-    if grad_override is None:
-        agent_keys = jax.random.split(k_noise, n)
-        grad_fn = functools.partial(_agent_gradient, cfg, loss_fn)
-        losses, g = jax.vmap(grad_fn)(state.x, batch, agent_keys)
-    else:
-        losses, g = grad_override
-    g = jax.tree_util.tree_map(lambda l: l.astype(cfg.grad_dtype), g)
+    with jax.named_scope("oracle"):
+        if grad_override is None:
+            agent_keys = jax.random.split(k_noise, n)
+            grad_fn = functools.partial(_agent_gradient, cfg, loss_fn)
+            losses, g = jax.vmap(grad_fn)(state.x, batch, agent_keys)
+        else:
+            losses, g = grad_override
+        g = jax.tree_util.tree_map(lambda l: l.astype(cfg.grad_dtype), g)
 
     # ---- comm rounds: track (lines 11-12) + step (lines 13-14) ------------
     # the state's own step counter is the absolute round index: it advances
@@ -229,15 +235,16 @@ def porter_step(
 
     new_state = PorterState(x=x, v=v, q_x=q_x, q_v=q_v, g_prev=g,
                             m_x=m_x, m_v=m_v, step=state.step + 1)
-    metrics = {
-        "loss": jnp.mean(losses),
-        "consensus_x": consensus_error(x),
-        "consensus_v": consensus_error(v),
-        "v_norm": clipping.tree_global_norm(v) / np.sqrt(n),
-        # two compressed streams (Q_x and Q_v) per round
-        "wire_bytes": jnp.asarray(2.0 * eng.wire_bytes(state.x),
-                                  jnp.float32),
-    }
+    with jax.named_scope("step.metrics"):
+        metrics = {
+            "loss": jnp.mean(losses),
+            "consensus_x": consensus_error(x),
+            "consensus_v": consensus_error(v),
+            "v_norm": clipping.tree_global_norm(v) / np.sqrt(n),
+            # two compressed streams (Q_x and Q_v) per round
+            "wire_bytes": jnp.asarray(2.0 * eng.wire_bytes(state.x),
+                                      jnp.float32),
+        }
     return new_state, metrics
 
 

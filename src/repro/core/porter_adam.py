@@ -79,10 +79,11 @@ def porter_adam_step(
     eng = _resolve_engine(engine, mixer, compressor, compress_fn)
 
     # gradients + tracking: identical to Algorithm 1 lines 4-12
-    agent_keys = jax.random.split(k_noise, n)
-    grad_fn = functools.partial(_agent_gradient, cfg, loss_fn)
-    losses, g = jax.vmap(grad_fn)(st.x, batch, agent_keys)
-    g = jax.tree_util.tree_map(lambda l: l.astype(cfg.grad_dtype), g)
+    with jax.named_scope("oracle"):
+        agent_keys = jax.random.split(k_noise, n)
+        grad_fn = functools.partial(_agent_gradient, cfg, loss_fn)
+        losses, g = jax.vmap(grad_fn)(st.x, batch, agent_keys)
+        g = jax.tree_util.tree_map(lambda l: l.astype(cfg.grad_dtype), g)
 
     if eng.overlap:
         # the x-side exchange reads only (st.x, st.q_x) -- independent of
@@ -121,10 +122,12 @@ def porter_adam_step(
 
     new_base = PorterState(x=x, v=v, q_x=q_x, q_v=q_v, g_prev=g, m_x=m_x,
                            m_v=m_v, step=st.step + 1)
-    metrics = {"loss": jnp.mean(losses), "consensus_x": consensus_error(x),
-               "consensus_v": consensus_error(v),
-               "wire_bytes": jnp.asarray(2.0 * eng.wire_bytes(st.x),
-                                         jnp.float32)}
+    with jax.named_scope("step.metrics"):
+        metrics = {"loss": jnp.mean(losses),
+                   "consensus_x": consensus_error(x),
+                   "consensus_v": consensus_error(v),
+                   "wire_bytes": jnp.asarray(2.0 * eng.wire_bytes(st.x),
+                                             jnp.float32)}
     return PorterAdamState(base=new_base, m=m, s=s), metrics
 
 

@@ -163,11 +163,12 @@ def dp_csgp_step(
     _, k_noise, k_cv, k_cx = jax.random.split(key, 4)
 
     # ---- stochastic gradients at the de-biased consensus estimate ---------
-    z = debias(state.x, state.xw)
-    agent_keys = jax.random.split(k_noise, n)
-    grad_fn = functools.partial(_agent_gradient, cfg, loss_fn)
-    losses, g = jax.vmap(grad_fn)(z, batch, agent_keys)
-    g = jax.tree_util.tree_map(lambda l: l.astype(cfg.grad_dtype), g)
+    with jax.named_scope("oracle"):
+        z = debias(state.x, state.xw)
+        agent_keys = jax.random.split(k_noise, n)
+        grad_fn = functools.partial(_agent_gradient, cfg, loss_fn)
+        losses, g = jax.vmap(grad_fn)(z, batch, agent_keys)
+        g = jax.tree_util.tree_map(lambda l: l.astype(cfg.grad_dtype), g)
 
     # ---- comm rounds: plain track + push-sum step -------------------------
     if eng.overlap:
@@ -194,16 +195,17 @@ def dp_csgp_step(
     new_state = DpCsgpState(x=x, v=v, q_x=q_x, q_v=q_v, g_prev=g,
                             m_x=m_x, m_v=m_v, xw=xw, q_w=q_w, m_w=m_w,
                             step=state.step + 1)
-    metrics = {
-        "loss": jnp.mean(losses),
-        # consensus on the de-biased estimates: the raw x drift toward the
-        # Perron vector is push-sum working, not disagreement
-        "consensus_x": consensus_error(debias(x, xw)),
-        "consensus_v": consensus_error(v),
-        "v_norm": clipping.tree_global_norm(v) / np.sqrt(n),
-        # v stream is a plain round, x stream carries the weight plane
-        "wire_bytes": jnp.asarray(
-            eng.wire_bytes(state.x)
-            + eng.wire_bytes(state.x, push_sum=True), jnp.float32),
-    }
+    with jax.named_scope("step.metrics"):
+        metrics = {
+            "loss": jnp.mean(losses),
+            # consensus on the de-biased estimates: the raw x drift toward
+            # the Perron vector is push-sum working, not disagreement
+            "consensus_x": consensus_error(debias(x, xw)),
+            "consensus_v": consensus_error(v),
+            "v_norm": clipping.tree_global_norm(v) / np.sqrt(n),
+            # v stream is a plain round, x stream carries the weight plane
+            "wire_bytes": jnp.asarray(
+                eng.wire_bytes(state.x)
+                + eng.wire_bytes(state.x, push_sum=True), jnp.float32),
+        }
     return new_state, metrics

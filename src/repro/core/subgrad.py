@@ -77,13 +77,17 @@ def subgrad_step(eta: float, gamma: float, loss_fn,
             g = clipping.tree_clip(g, tau, clip_mode)
         return loss, g
 
-    losses, g = jax.vmap(agent_subgrad)(state.x, batch, keys)
+    with jax.named_scope("oracle"):
+        losses, g = jax.vmap(agent_subgrad)(state.x, batch, keys)
     # nonsmooth rate's schedule: eta_t = eta / sqrt(t + 1)
     eta_t = eta * jax.lax.rsqrt(state.step.astype(jnp.float32) + 1.0)
     x_half = jax.tree_util.tree_map(
         lambda x0, gg: x0 - eta_t * gg.astype(x0.dtype), state.x, g)
     x, q, m = eng.gossip_apply(k_c, x_half, state.q, state.m, gamma,
                                t=state.step)
-    return SubgradState(x=x, q=q, m=m, step=state.step + 1), {
-        "loss": jnp.mean(losses), "consensus_x": consensus_error(x),
-        "wire_bytes": jnp.asarray(eng.wire_bytes(state.x), jnp.float32)}
+    with jax.named_scope("step.metrics"):
+        metrics = {"loss": jnp.mean(losses),
+                   "consensus_x": consensus_error(x),
+                   "wire_bytes": jnp.asarray(eng.wire_bytes(state.x),
+                                             jnp.float32)}
+    return SubgradState(x=x, q=q, m=m, step=state.step + 1), metrics

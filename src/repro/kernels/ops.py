@@ -123,6 +123,12 @@ def wire_qsgd_unpack(word: jax.Array, scale: jax.Array, levels: int,
     return _wp.qsgd_unpack(word, scale, levels, interpret=interpret)
 
 
+def _sr_bits(key: jax.Array, shape) -> jax.Array:
+    """The u32 words a stochastic-rounding cast of ``shape`` consumes."""
+    with jax.named_scope("engine.sr_bits"):
+        return jax.random.bits(key, shape, jnp.uint32)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sr_cast(x: jax.Array, key: jax.Array,
             interpret: bool | None = None) -> jax.Array:
@@ -133,14 +139,14 @@ def sr_cast(x: jax.Array, key: jax.Array,
     (the pattern wire_qsgd_pack uses for its dither noise).
     """
     interpret = default_interpret() if interpret is None else interpret
-    bits = jax.random.bits(key, x.shape, jnp.uint32)
+    bits = _sr_bits(key, x.shape)
     return _srk.sr_cast(x.astype(jnp.float32), bits, interpret=interpret)
 
 
 @jax.jit
 def sr_cast_ref(x: jax.Array, key: jax.Array) -> jax.Array:
     """jnp reference for :func:`sr_cast` (same bits draw, no pallas)."""
-    bits = jax.random.bits(key, x.shape, jnp.uint32)
+    bits = _sr_bits(key, x.shape)
     return _srk.sr_cast_ref(x.astype(jnp.float32), bits)
 
 
@@ -156,12 +162,12 @@ def sr_cast_leaf(x: jax.Array, key: jax.Array) -> jax.Array:
     whole-array draw from a replicated key lowers with partitioner
     collectives on the agent mesh)."""
     if x.ndim == 0:
-        bits = jax.random.bits(key, x.shape, jnp.uint32)
-        return _srk.sr_cast_ref(x.astype(jnp.float32), bits)
-    ks = jax.vmap(lambda i: jax.random.fold_in(key, i))(
-        jnp.arange(x.shape[0]))
-    bits = jax.vmap(
-        lambda kk, row: jax.random.bits(kk, row.shape, jnp.uint32))(ks, x)
+        return _srk.sr_cast_ref(x.astype(jnp.float32), _sr_bits(key, ()))
+    with jax.named_scope("engine.sr_bits"):
+        ks = jax.vmap(lambda i: jax.random.fold_in(key, i))(
+            jnp.arange(x.shape[0]))
+        bits = jax.vmap(
+            lambda kk, row: jax.random.bits(kk, row.shape, jnp.uint32))(ks, x)
     return _srk.sr_cast_ref(x.astype(jnp.float32), bits)
 
 

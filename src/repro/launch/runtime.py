@@ -118,9 +118,10 @@ class ChunkRunner:
     jitted: Any
 
     def __call__(self, state, key, start: int = 0):
-        if self.donate:
-            state = _dealias(state)
-        return self.jitted(state, key, jnp.asarray(start, jnp.int32))
+        with jax.profiler.TraceAnnotation("runner.dispatch", start=start):
+            if self.donate:
+                state = _dealias(state)
+            return self.jitted(state, key, jnp.asarray(start, jnp.int32))
 
     def lower(self, state_shapes, key_shape=None):
         """Abstract lowering (dry-run path): no buffer is materialized."""
@@ -162,10 +163,11 @@ def make_runner(algo, source: BatchSource, chunk: int, *, donate: bool = True,
             # stream is chunking- and restart-invariant (no DP-noise
             # replay on resume)
             kb, ks = jax.random.split(jax.random.fold_in(key, t))
-            batch = source(kb, t)
-            if batch_sharding is not None:
-                batch = jax.lax.with_sharding_constraint(batch,
-                                                         batch_sharding)
+            with jax.named_scope("runner.batch"):
+                batch = source(kb, t)
+                if batch_sharding is not None:
+                    batch = jax.lax.with_sharding_constraint(batch,
+                                                             batch_sharding)
             st, metrics = step(st, batch, ks)
             return st, metrics
 
@@ -213,6 +215,9 @@ def run_chunked(algo, source: BatchSource, state, key, steps: int, *,
         state, key, metrics = runner(state, key, t)
         t += size
         if on_chunk is not None:
-            if on_chunk(t - size, t, state, metrics) is False:
+            with jax.profiler.TraceAnnotation("runner.on_chunk",
+                                              start=t - size):
+                go_on = on_chunk(t - size, t, state, metrics)
+            if go_on is False:
                 break
     return state, key
