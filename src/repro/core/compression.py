@@ -16,6 +16,15 @@ Instances implemented here:
                     Q satisfies E||Q(x)-x||^2 <= omega ||x||^2, so the scaled
                     version Q/(1+omega) is a rho = 1/(1+omega) compressor.
 
+``top_k`` selects without a sort: it finds the k-th largest |x| on the bit
+patterns of |x| (u16 for bf16/f16, u32 for f32; for non-negative floats the
+order of the patterns is the order of the values), two magnitude bits per
+counting pass (8 passes for 16-bit floats, 16 for f32), keeps every coordinate
+above that cut and, among those equal to it, the lowest flat indices until k
+are kept (``jax.lax.top_k``'s tie rule), and writes the dense output with a
+``where``.  The operator is Example 2's, global over the whole leaf; the kept
+set is exactly the one a sort of |x| gives.
+
 All compressors operate on flat vectors; :func:`compress_tree` maps a
 compressor over an agent-stacked pytree, giving every (agent, leaf) pair an
 independent PRNG stream.
@@ -134,17 +143,82 @@ def random_k(frac: float) -> Compressor:
     return Compressor(f"random_k({frac})", float(frac), fn)
 
 
+# Magnitude bits the cut search sets per counting pass (see ``_topk_dense``):
+# on a v5e one exchange of 2 x 172M bf16 took 51 ms at 1 bit, 33 at 2, 32 at 4.
+_BITS_PER_PASS = 2
+
+
+def _keep_top(v: jax.Array, key: jax.Array, cut, n_ge, k: int) -> jax.Array:
+    """Zero all of the rows ``v`` but the entries whose ``key`` is above
+    ``cut`` and, of those equal to it, the lowest flat indices until k are
+    kept (``n_ge`` entries are at or above the cut): every tie of the rows
+    before row b, and a prefix of row b's."""
+    at = key == cut
+    per_row = jnp.sum(at, axis=1, dtype=jnp.int32)
+    upto = jnp.cumsum(per_row)
+    room = k - (n_ge - upto[-1])
+    b = jnp.minimum(jnp.sum(upto <= room, dtype=jnp.int32), v.shape[0] - 1)
+    in_b = (upto[b] - per_row[b]
+            + jnp.cumsum(key[b] == cut, dtype=jnp.int32)) <= room
+    row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    ties = at & ((row < b) | ((row == b) & in_b))
+    return jnp.where((key > cut) | ties, v, jnp.zeros_like(v))
+
+
 def _topk_dense(x: jax.Array, k: int) -> jax.Array:
-    flat = x.reshape(-1)
-    d = flat.shape[0]
-    k = min(k, d)
-    _, idx = jax.lax.top_k(jnp.abs(flat), k)
-    out = jnp.zeros_like(flat).at[idx].set(flat[idx])
+    """Keep the k largest |x|, lowest flat index first among equals.
+
+    The cut, the k-th largest |x|, is the largest t with at least k entries
+    at or above it on the magnitude bits (``key``), so it is found from the
+    top bit down: each pass counts the entries at or above the
+    ``2**_BITS_PER_PASS - 1`` candidates that extend t by one digit and
+    keeps the largest candidate that still has k of them.  The last pass
+    also writes the output.  Every pass reads the leaf from the loop's
+    carry, so the selection and the values kept come from one copy of x
+    even where XLA would otherwise recompute x's producer per consumer.
+    """
+    if k >= x.size:
+        return x
+    v = x.reshape(-1, x.shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
+    width = x.dtype.itemsize * 8
+    uint = {16: jnp.uint16, 32: jnp.uint32}[width]
+    r = _BITS_PER_PASS
+    passes = -(-(width - 1) // r)
+    assert passes * r <= width, (width, r)
+
+    def one_pass(i, carry):
+        base, n, v = carry
+        key = (jax.lax.bitcast_convert_type(v, uint)
+               & jnp.asarray((1 << (width - 1)) - 1, uint))
+        t = base
+        shift = ((passes - 1 - i) * r).astype(uint)
+        for digit in range(1, 2 ** r):
+            cand = base | (jnp.asarray(digit, uint) << shift)
+            count = jnp.sum(key >= cand, dtype=jnp.int32)
+            t, n = (jnp.where(count >= k, cand, t),
+                    jnp.where(count >= k, count, n))
+        v = jax.lax.cond(i == passes - 1,
+                         functools.partial(_keep_top, k=k),
+                         lambda v, *_: v, v, key, t, n)
+        return t, n, v
+
+    _, _, out = jax.lax.fori_loop(
+        0, passes, one_pass,
+        (jnp.zeros((), uint), jnp.asarray(x.size, jnp.int32), v))
     return out.reshape(x.shape)
 
 
 def top_k(frac: float) -> Compressor:
-    """Paper Example 2: keep the k = frac*d largest-magnitude coordinates."""
+    """Paper Example 2: keep the k = frac*d largest-magnitude coordinates.
+
+    Global over the whole leaf, k = max(round(frac * d), 1).  The cut is the
+    k-th largest |x|, found on the bits of |x| (for non-negative floats the
+    order of the values is the order of their patterns) by counting passes
+    that set two magnitude bits each: 8 for bf16 and f16, 16 for f32.  Among
+    entries equal to the cut the lowest flat indices are kept, the tie rule
+    of ``jax.lax.top_k``, so the kept set is the one a sort of |x| gives; no
+    sort and no scatter.
+    """
 
     def fn(key, x):
         del key

@@ -1,6 +1,8 @@
 """Property tests: every compressor satisfies Definition 3,
 E||C(x) - x||^2 <= (1 - rho) ||x||^2, plus scheme-specific facts."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,6 +68,76 @@ def test_topk_keeps_largest():
     x = jnp.asarray([0.1, -5.0, 0.2, 3.0, -0.05])
     y = C.make_compressor("top_k", frac=0.4)(None, x)
     np.testing.assert_allclose(y, [0.0, -5.0, 0.0, 3.0, 0.0])
+
+
+def _sort_top_k(x, frac):
+    """The selection by a full sort: ``jax.lax.top_k`` of |x| (lowest index
+    first among equals) and a scatter of the kept values."""
+    flat = x.reshape(-1)
+    k = min(max(int(round(frac * flat.size)), 1), flat.size)
+    _, idx = jax.lax.top_k(jnp.abs(flat), k)
+    return jnp.zeros_like(flat).at[idx].set(flat[idx]).reshape(x.shape)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _values(kind, shape, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "normal":
+        return rng.normal(size=shape)
+    if kind == "levels":    # the cut falls inside a run of equal |x|
+        return rng.choice([-1.5, -0.25, 0.25, 1.5], size=shape)
+    return rng.choice([-np.inf, np.inf, -0.0, 0.0, 3.0, -3.0, 0.5],
+                      size=shape)
+
+
+# (shape, frac): k = 1, k = d, odd and non-power-of-two d, round(frac * d)
+# rounding down (663 * 0.05 = 33.15) and up (2112 * 0.05 = 105.6)
+EXACT_SIZES = [((997,), 0.001), ((5, 7), 1.0), ((13, 17, 3), 0.05),
+               ((64, 33), 0.05), ((1000,), 0.999)]
+
+
+@pytest.mark.parametrize("shape,frac", EXACT_SIZES)
+@pytest.mark.parametrize("kind", ["normal", "levels", "special"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_topk_matches_sort_bit_for_bit(dtype, kind, shape, frac):
+    x = jnp.asarray(_values(kind, shape, sum(shape)), dtype)
+    got = jax.jit(lambda v: C.top_k(frac)(None, v))(x)
+    np.testing.assert_array_equal(_bits(got), _bits(_sort_top_k(x, frac)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_topk_stacked_matches_sort(dtype):
+    """Through ``compress_stacked``, each agent's row of a leaf is selected
+    on its own, as the sort selected it."""
+    from repro.core.comm_round import compress_stacked
+
+    tree = {"w": jnp.asarray(_values("levels", (3, 40, 24), 1), dtype),
+            "b": jnp.asarray(_values("normal", (3, 77), 2), dtype)}
+    out = jax.jit(lambda t: compress_stacked(C.top_k(0.05),
+                                             jax.random.PRNGKey(0), t))(tree)
+    for name, leaf in tree.items():
+        for agent in range(leaf.shape[0]):
+            np.testing.assert_array_equal(
+                _bits(out[name][agent]),
+                _bits(_sort_top_k(leaf[agent], 0.05)))
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+def test_topk_compiles_without_sort_or_scatter(stacked):
+    from repro.core.comm_round import compress_stacked
+
+    comp = C.top_k(0.05)
+    x = jax.ShapeDtypeStruct((2, 4096), jnp.bfloat16)
+    if stacked:
+        fn = lambda v: compress_stacked(comp, jax.random.PRNGKey(0), [v])
+    else:
+        fn = lambda v: comp(None, v)
+    text = jax.jit(fn).lower(x).compile().as_text()
+    assert not re.search(r"\b(sort|scatter)\(", text)
 
 
 def test_pack_unpack_roundtrip():

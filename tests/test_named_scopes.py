@@ -89,10 +89,13 @@ def test_every_layer_is_named(program):
 
 
 def test_sorts_lie_under_compression(program):
+    """The chunk program sorts nothing: the global top_k counts instead, and
+    its counting reductions lie under ``engine.compress``."""
     _, ops = program
-    sorts = [op_name for _, opcode, op_name in ops if opcode == "sort"]
-    assert sorts
-    assert all("engine.compress" in _parts(s) for s in sorts), sorts
+    assert not [o for _, opcode, o in ops if opcode == "sort"]
+    counts = [p for p in (_parts(o) for _, opcode, o in ops
+                          if opcode == "reduce") if "compress" in p]
+    assert counts and all("engine.compress" in p for p in counts)
 
 
 def test_benchmark_scopes_nest_in_the_programs(program):
